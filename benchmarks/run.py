@@ -31,6 +31,8 @@ ALL = [
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     only = set(sys.argv[1:])
     print("name,us_per_call,derived")
     failures = 0

@@ -1,7 +1,6 @@
 import jax, jax.numpy as jnp, numpy as np
 from repro.kernels.ref import paged_attention_ref, block_copy_ref
-from repro.kernels.paged_attention import paged_attention
-from repro.kernels.block_copy import block_copy, block_copy_grouped
+from repro.kernels.ops import copy_block_runs, copy_blocks, paged_attention
 
 key = jax.random.PRNGKey(0)
 B, Hq, Hkv, D, bs, nb, npages = 3, 8, 2, 64, 16, 32, 4
@@ -23,20 +22,17 @@ si = jnp.array([3, 7, 1], jnp.int32)
 di = jnp.array([0, 5, 11], jnp.int32)
 ref2 = block_copy_ref(src, dst, si, di)
 try:
-    out2 = block_copy(src, dst, si, di)
+    out2 = copy_blocks(src, dst, si, di)
     print("block_copy maxerr", float(jnp.max(jnp.abs(ref2 - out2))))
 except Exception as e:
     print("block_copy FAIL:", type(e).__name__, e)
 
 # grouped
-ss = jnp.array([0, 8], jnp.int32)
-ds = jnp.array([2, 6], jnp.int32)
-ls = jnp.array([2, 4], jnp.int32)
 ref3 = dst
 for s, d, l in [(0, 2, 2), (8, 6, 4)]:
     ref3 = ref3.at[d:d + l].set(src[s:s + l])
 try:
-    out3 = block_copy_grouped(src, dst, ss, ds, ls, run_blocks=4)
+    out3 = copy_block_runs(src, dst, [(0, 2), (8, 4)], [2, 6])
     print("block_copy_grouped maxerr", float(jnp.max(jnp.abs(ref3 - out3))))
 except Exception as e:
     print("block_copy_grouped FAIL:", type(e).__name__, e)

@@ -78,7 +78,8 @@ def _is_jax_jit(node: ast.expr) -> bool:
 # params exactly like ``jax.jit(f, ...)`` would, so the indexer must
 # see through them or every mesh-sharded step shows up as an undonated
 # unbucketed hot-path jit (false FS001/FS002/FS006).
-_SPMD_WRAPPERS = ("shard_map", "jax.experimental.shard_map.shard_map",
+_SPMD_WRAPPERS = ("shard_map", "jax.shard_map",
+                  "jax.experimental.shard_map.shard_map",
                   "shmap", "pjit", "jax.experimental.pjit.pjit")
 
 

@@ -63,8 +63,12 @@ class PoolSpec:
 
 class PagedPools:
     def __init__(self, spec: PoolSpec, with_data: bool = True, mesh=None,
-                 stage_blocks: int = 16):
-        """``mesh``: a ("data", "model") jax mesh — the GPU pool's KV
+                 stage_blocks: int = 16, device=None):
+        """``device``: the JAX device the GPU pool lives on without a
+        mesh (default: the default device); swap-in slabs are uploaded
+        straight to it.
+
+        ``mesh``: a ("data", "model") jax mesh — the GPU pool's KV
         head axis is then partitioned over ``model`` (NamedSharding,
         DESIGN.md §9) and every staged swap runs per shard: the slab
         stays head-sharded and the host link carries one transfer per
@@ -84,6 +88,7 @@ class PagedPools:
         if mesh is not None and mesh.size == 1:
             mesh = None
         self.mesh = mesh
+        self.device = device
         self.n_shards = 1 if mesh is None else int(mesh.shape["model"])
         if mesh is not None:
             assert spec.n_kv_heads % self.n_shards == 0, (
@@ -99,7 +104,7 @@ class PagedPools:
             s = spec
             self.gpu = jnp.zeros((s.n_layers, 2, s.num_gpu_blocks,
                                   s.block_size, s.n_kv_heads, s.head_dim),
-                                 jnp.bfloat16)
+                                 jnp.bfloat16, device=device)
             if mesh is not None:
                 from repro.models.sharding import pool_pspec
                 self.gpu = jax.device_put(
@@ -214,7 +219,7 @@ class PagedPools:
             pos += cnt
             # ONE h2d per shard (bucketed slab; head-sharded under a mesh)
             if self.mesh is None:
-                dev = jnp.asarray(slab.view(jnp.bfloat16))
+                dev = jax.device_put(slab.view(jnp.bfloat16), self.device)
             else:
                 dev = jax.device_put(slab.view(jnp.bfloat16), sharding)
             self.staged_in_calls += 1

@@ -1,8 +1,9 @@
 """Architecture config registry.
 
-``get_config(arch_id)`` returns the full published config (dry-run only);
-``get_smoke_config(arch_id)`` returns the reduced same-family variant used
-by CPU smoke tests and the serving-engine examples.
+``get_config(arch_id)`` returns the full published config, which
+real-mode serving runs on an accelerator (``repro.launch.serve``,
+``chip_smoke.py``); ``get_smoke_config(arch_id)`` returns the reduced
+same-family variant that CPU tests and CPU real-mode runs serve.
 """
 from __future__ import annotations
 

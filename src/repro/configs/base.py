@@ -1,9 +1,10 @@
 """Model/architecture configuration dataclasses.
 
 Every assigned architecture gets one module in ``repro.configs`` exporting
-``CONFIG`` (the full published config, used only via the dry-run) and
-``smoke_config()`` (a reduced same-family variant for CPU smoke tests:
-<=2 layers, d_model<=512, <=4 experts).
+``CONFIG`` (the full published config, which real-mode serving runs on an
+accelerator) and ``smoke_config()`` (a reduced same-family variant for
+CPU tests, where the kernels are interpreted: <=2 layers, d_model<=512,
+<=4 experts).
 """
 from __future__ import annotations
 

@@ -136,7 +136,8 @@ class DecodeRunner:
     def __init__(self, model_bundle: dict, *, block_size: int,
                  trash_block: int, min_pages_bucket: int = 1,
                  temperature: float = 0.0, top_k: int = 0,
-                 top_p: float = 1.0, seed: int = 0, mesh=None):
+                 top_p: float = 1.0, seed: int = 0, mesh=None,
+                 device=None):
         self.mb = model_bundle
         self.bs = block_size
         self.trash = trash_block
@@ -150,7 +151,13 @@ class DecodeRunner:
         if mesh is not None and mesh.size == 1:
             mesh = None
         self._mesh = mesh
+        # ``device``: without a mesh, the params and every persistent
+        # decode array live here (a no-op move when the bundle already
+        # does); a replica on another chip gets its own params copy
+        self._device = device
         self._params = model_bundle["params"]
+        if device is not None and mesh is None:
+            self._params = jax.device_put(self._params, device)
         if mesh is not None:
             from repro.models.paged import shardable_heads
             from repro.models.sharding import serving_param_pspecs
@@ -267,12 +274,9 @@ class DecodeRunner:
             act[i] = True
             smp[i] = self._row_sampling(v)
         self._free = list(range(len(views), batch_bucket))
-        self._bt = jnp.asarray(bt)
-        self._ctx = jnp.asarray(ctx)
-        self._tok = jnp.asarray(tok)
-        self._keys = jnp.asarray(keys)
-        self._active = jnp.asarray(act)
-        self._sampling = jnp.asarray(smp)
+        self._bt, self._ctx, self._tok, self._keys, self._active, \
+            self._sampling = jax.device_put((bt, ctx, tok, keys, act, smp),
+                                            self._device)
         self._active_rows = frozenset(range(len(views)))
 
     def _scatter_rows(self, pending: Dict[int, Tuple[Tuple[int, ...],
@@ -361,7 +365,7 @@ class DecodeRunner:
             self._active_rows = active
             act = np.zeros((self._batch_bucket,), bool)
             act[list(active)] = True
-            self._active = jnp.asarray(act)
+            self._active = jax.device_put(act, self._device)
 
     # ------------------------------------------------------------------
     # the step
@@ -452,7 +456,7 @@ class DecodeRunner:
             self._active_rows = self._active_rows - {row}
             act = np.zeros((self._batch_bucket,), bool)
             act[list(self._active_rows)] = True
-            self._active = jnp.asarray(act)
+            self._active = jax.device_put(act, self._device)
 
     def invariant_report(self, live_rids) -> List[str]:
         """Row-map validation for the engine sanitizer (DESIGN.md §7).

@@ -62,7 +62,7 @@ from repro.core.scheduler import PriorityScheduler, Request, ReqState
 from repro.core.swap_manager import MultithreadingSwapManager, SimClock
 from repro.data.priority import PriorityTrace
 from repro.data.sharegpt import Conversation, Turn
-from repro.io.cost_model import IterationCostModel
+from repro.io.cost_model import IterationCostModel, serving_hardware
 
 
 @dataclass
@@ -166,11 +166,17 @@ class ServingEngine:
                  model_bundle: Optional[dict] = None,
                  event_sink: Optional[Callable[[RequestEvent], None]] = None,
                  keep_events: bool = True,
-                 stream_tokens: bool = False):
+                 stream_tokens: bool = False,
+                 device=None):
+        """``device``: the one JAX device a single-device real-mode
+        engine keeps its pool, params and decode state on (default: the
+        first device).  Replicas of a multi-chip host each get their
+        own.  Ignored in sim mode and under a mesh."""
         self.config = config
         pol = config.policy
         self.clock = SimClock()
         self.metrics = EngineMetrics()
+        hardware = config.hardware
 
         group_blocks = pol.initial_group_blocks if pol.use_block_groups else 1
         self.gpu_mgr = DynamicBlockGroupManager(
@@ -188,15 +194,23 @@ class ServingEngine:
         # single-device engine; otherwise the decode/prefill steps and
         # the staged swap plane run tensor-parallel over ``model``
         self.mesh = None
+        self.device = None
         if config.mode == "real":
             assert model_bundle is not None, "real mode needs a model bundle"
+            import jax
             from repro.launch.mesh import make_serving_mesh
             self.mesh = make_serving_mesh(config.mesh_shape)
+            if self.mesh is None:
+                self.device = device or jax.devices()[0]
+            # swap and iteration costs for the chip actually served on
+            hardware = serving_hardware(
+                self.device or self.mesh.devices.flat[0], config.hardware)
             cfg = model_bundle["cfg"]
             spec = PoolSpec.from_config(cfg, config.num_gpu_blocks,
                                         config.num_cpu_blocks,
                                         config.block_size)
-            self.pools = PagedPools(spec, with_data=True, mesh=self.mesh)
+            self.pools = PagedPools(spec, with_data=True, mesh=self.mesh,
+                                    device=self.device)
             self.block_bytes = spec.block_bytes()
             from repro.models.params import count_params_analytic
             model_params = count_params_analytic(cfg)
@@ -210,14 +224,14 @@ class ServingEngine:
         self.block_bytes = self.block_bytes * pol.swap_wire_bytes_per_elem // 2
 
         self.swap = MultithreadingSwapManager(
-            config.hardware, self.pools,
+            hardware, self.pools,
             async_enabled=pol.use_async_swap,
             adaptive=pol.adaptive_async,
             r_info_window=config.r_info_window,
             max_copy_retries=config.swap_max_retries,
             retry_backoff_us=config.swap_retry_backoff_us)
         self.iter_cost = IterationCostModel(
-            config.hardware, model_params=model_params,
+            hardware, model_params=model_params,
             kv_bytes_per_token=kv_tok)
 
         self.trace = trace or PriorityTrace()
@@ -260,7 +274,8 @@ class ServingEngine:
                 model_bundle, block_size=config.block_size,
                 trash_block=self._trash_block,
                 temperature=config.temperature, top_k=config.top_k,
-                top_p=config.top_p, seed=config.seed, mesh=self.mesh)
+                top_p=config.top_p, seed=config.seed, mesh=self.mesh,
+                device=self.device)
         # serving-API surface: step outputs, event log, streaming
         self._outs: Dict[int, RequestOutput] = {}
         self.events: Optional[List[RequestEvent]] = [] if keep_events else None

@@ -516,8 +516,7 @@ async def _smoke_async(events_prefix: str) -> Dict[str, object]:
 
 
 def run_smoke(events_prefix: str) -> Dict[str, object]:
-    out = asyncio.get_event_loop().run_until_complete(
-        _smoke_async(events_prefix))
+    out = asyncio.run(_smoke_async(events_prefix))
     from repro.frontend.router import load_event_log
     from repro.launch.serve import validate_event_log
 

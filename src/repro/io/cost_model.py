@@ -55,6 +55,27 @@ TPU_V5E_HOST = HardwareSpec(
 
 PRESETS = {h.name: h for h in (A10_PCIE4, A100_PCIE4, TPU_V5E_HOST)}
 
+# Real-mode cost model per accelerator, keyed by ``device.device_kind``
+# as JAX reports it.  The TPU v5e entry's compute and HBM peaks are the
+# published ones (Google Cloud, "TPU v5e"); its host-link figures are
+# not yet measured on the chip.
+DEVICE_KINDS = {"TPU v5 lite": TPU_V5E_HOST}
+
+
+def serving_hardware(device, preset: HardwareSpec) -> HardwareSpec:
+    """The cost model a real-mode engine on ``device`` schedules with.
+    An accelerator's comes from ``DEVICE_KINDS``; a kind missing there
+    is an error, never a silent GPU preset.  The CPU (the interpret-mode
+    test path) keeps the configuration's explicit ``preset``."""
+    if device.platform == "cpu":
+        return preset
+    try:
+        return DEVICE_KINDS[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no cost model for device kind {device.device_kind!r} "
+            f"({device.platform}); add it to DEVICE_KINDS") from None
+
 
 def transfer_time_us(hw: HardwareSpec, nbytes: int, h2d: bool) -> float:
     """Cost of ONE transfer op of ``nbytes`` contiguous bytes."""

@@ -1,11 +1,19 @@
 """Pallas block-copy kernels — the swap data plane.
 
-``block_copy``: scatter/gather copy of individual KV blocks through an
-index list (the vLLM per-block baseline).  ``block_copy_grouped`` copies
-*runs* of contiguous blocks; on real TPU each run lowers to one large DMA
-(the Dynamic Block Group Manager's whole point — fewer descriptors, full
-bandwidth), expressed here by blocking the grid over runs with the run
-extent as the second block dimension.
+Every copy is a list of *runs*: ``src[:, s:s+n] -> dst[:, d:d+n]`` along
+the block axis (axis 1) of two arrays with equal trailing dims.  One grid
+step per run issues the run as HBM->HBM DMAs (``memory_space=pl.ANY`` +
+``pltpu.make_async_copy``).  The run length ``n`` is a runtime scalar, so
+the run is cut at its binary digits: at most ``log2(run_blocks) + 1``
+DMAs of static power-of-two extents, each one contiguous descriptor —
+fewer, larger transfers, the Dynamic Block Group Manager's point.  No
+block passes through VMEM, so no tiling rule constrains the trailing
+``(bs, Hkv, D)`` layout, and one shard's local heads under ``shard_map``
+copy as they are.  Blocks outside the runs are never written: ``dst`` is
+aliased into the output.
+
+``block_copy`` (one block per index, the vLLM per-block baseline) and
+``block_copy_grouped`` are the 2-D ``(nb, E)`` views of the same kernel.
 """
 from __future__ import annotations
 
@@ -18,176 +26,71 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _copy_kernel(src_idx_ref, dst_idx_ref, d_ref, s_ref, o_ref):
-    o_ref[...] = s_ref[...]
+def _runs_kernel(src_ref, dst_ref, len_ref, s_hbm, d_hbm, o_hbm, sem, *,
+                 nbits: int):
+    del d_hbm                                    # aliased to o_hbm
+    r = pl.program_id(0)
+    n = len_ref[r]
+    copies = []
+    for k in range(nbits):
+        # digit k of n covers blocks [off, off + 2^k), off = higher digits
+        off = (n >> (k + 1)) << (k + 1)
+        copies.append((k, pltpu.make_async_copy(
+            s_hbm.at[:, pl.ds(src_ref[r] + off, 1 << k)],
+            o_hbm.at[:, pl.ds(dst_ref[r] + off, 1 << k)], sem.at[k])))
+    for k, c in copies:
+        pl.when((n >> k) & 1 == 1)(c.start)
+    for k, c in copies:
+        pl.when((n >> k) & 1 == 1)(c.wait)
+
+
+def copy_runs(src, dst, src_starts, dst_starts, run_lens, *,
+              run_blocks: int, interpret: bool) -> jnp.ndarray:
+    """Copy runs ``src[:, s:s+l] -> dst[:, d:d+l]`` and return the new
+    ``dst`` (aliased: callers jit this with ``dst`` donated for an
+    in-place write).  ``src``/``dst``: ``(C, nb, ...)`` with equal
+    trailing dims; ``src_starts``/``dst_starts``/``run_lens``: ``(n_runs,)``
+    int32 — zero-length runs copy nothing; ``run_blocks``: static bound
+    on every run length.  NOT jitted here: the jitted (bucketed,
+    donating) wrappers live in ``kernels/ops.py``."""
+    nbits = max(1, run_blocks).bit_length()
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(src_starts.shape[0],),
+        in_specs=[any_spec, any_spec],
+        out_specs=any_spec,
+        scratch_shapes=[pltpu.SemaphoreType.DMA((nbits,))],
+    )
+    return pl.pallas_call(
+        functools.partial(_runs_kernel, nbits=nbits),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(dst.shape, dst.dtype),
+        input_output_aliases={4: 0},       # dst (5th operand) -> output
+        interpret=interpret,
+    )(src_starts.astype(jnp.int32), dst_starts.astype(jnp.int32),
+      run_lens.astype(jnp.int32), src, dst)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def block_copy(src_pool, dst_pool, src_blocks, dst_blocks,
-               interpret: bool = True) -> jnp.ndarray:
-    """Copy src_pool[src_blocks[i]] -> dst_pool[dst_blocks[i]].
-
-    src_pool: (nb_src, E); dst_pool: (nb_dst, E); indices: (n,) int32.
-    Returns the updated dst pool (dst aliased in-place on TPU).
-    """
-    n = src_blocks.shape[0]
-    E = src_pool.shape[1]
-
-    def s_map(i, src_idx, dst_idx):
-        return (src_idx[i], 0)
-
-    def o_map(i, src_idx, dst_idx):
-        return (dst_idx[i], 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(n,),
-        in_specs=[pl.BlockSpec((1, E), o_map),     # aliased dst (unread)
-                  pl.BlockSpec((1, E), s_map)],
-        out_specs=pl.BlockSpec((1, E), o_map),
-    )
-    return pl.pallas_call(
-        _copy_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(dst_pool.shape, dst_pool.dtype),
-        input_output_aliases={2: 0},      # dst_pool (3rd operand) -> output
-        interpret=interpret,
-    )(src_blocks.astype(jnp.int32), dst_blocks.astype(jnp.int32),
-      dst_pool, src_pool)
-
-
-def _copy_run_kernel(src_idx_ref, dst_idx_ref, len_ref, d_ref, s_ref, o_ref):
-    r = pl.program_id(0)
-    j = pl.program_id(1)
-
-    @pl.when(j < len_ref[r])
-    def _copy():
-        o_ref[...] = s_ref[...]
+def block_copy(src_pool, dst_pool, src_blocks, dst_blocks, *,
+               interpret: bool) -> jnp.ndarray:
+    """Copy src_pool[src_blocks[i]] -> dst_pool[dst_blocks[i]], one DMA
+    per block.  src_pool: (nb_src, E); dst_pool: (nb_dst, E); indices:
+    (n,) int32.  Returns the updated dst pool."""
+    ones = jnp.ones(src_blocks.shape, jnp.int32)
+    return copy_runs(src_pool[None], dst_pool[None], src_blocks, dst_blocks,
+                     ones, run_blocks=1, interpret=interpret)[0]
 
 
 @functools.partial(jax.jit, static_argnames=("run_blocks", "interpret"))
 def block_copy_grouped(src_pool, dst_pool, src_starts, dst_starts, run_lens,
-                       run_blocks: int, interpret: bool = True) -> jnp.ndarray:
+                       *, run_blocks: int, interpret: bool) -> jnp.ndarray:
     """Copy contiguous runs: src_pool[s:s+l] -> dst_pool[d:d+l] per run.
-
-    Grid is (n_runs, run_blocks); inside a run the block index advances with
-    unit stride so consecutive grid steps touch *adjacent* HBM — the Mosaic
-    pipeline coalesces these into streaming DMA (one descriptor chain per
-    run), unlike the scattered per-block baseline above.
-    ``run_blocks`` is the static max run extent; shorter runs mask off.
-    """
-    n_runs = src_starts.shape[0]
-    nb_src = src_pool.shape[0]
-    nb_dst = dst_pool.shape[0]
-    E = src_pool.shape[1]
-
-    def s_map(r, j, src, dst, lens):
-        return (jnp.minimum(src[r] + j, nb_src - 1), 0)
-
-    def o_map(r, j, src, dst, lens):
-        return (jnp.minimum(dst[r] + j, nb_dst - 1), 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(n_runs, run_blocks),
-        in_specs=[pl.BlockSpec((1, E), o_map),   # aliased dst (unread)
-                  pl.BlockSpec((1, E), s_map)],
-        out_specs=pl.BlockSpec((1, E), o_map),
-    )
-    return pl.pallas_call(
-        _copy_run_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(dst_pool.shape, dst_pool.dtype),
-        input_output_aliases={3: 0},
-        interpret=interpret,
-    )(src_starts.astype(jnp.int32), dst_starts.astype(jnp.int32),
-      run_lens.astype(jnp.int32), dst_pool, src_pool)
-
-
-def _gather_run_kernel(src_idx_ref, dst_idx_ref, len_ref, d_ref, s_ref, o_ref):
-    r = pl.program_id(0)
-    j = pl.program_id(1)
-
-    @pl.when(j < len_ref[r])
-    def _copy():
-        o_ref[...] = s_ref[...]
-
-
-def _copy_runs_3d(src, dst, src_starts, dst_starts, run_lens,
-                  run_blocks: int, interpret: bool) -> jnp.ndarray:
-    """Shared runs-copy over 3-D block pools: src[:, s:s+l] ->
-    dst[:, d:d+l] per run, grid (n_runs, run_blocks), masked steps and
-    pad blocks keep dst's content through the output alias.  NOT jitted
-    here: the jitted (bucketed, donating) wrappers live in
-    ``kernels/ops.py``."""
-    n_runs = src_starts.shape[0]
-    C, n_src, E = src.shape
-    n_dst = dst.shape[1]
-
-    def s_map(r, j, srcs, dsts, lens):
-        return (0, jnp.minimum(srcs[r] + j, n_src - 1), 0)
-
-    def o_map(r, j, srcs, dsts, lens):
-        return (0, jnp.minimum(dsts[r] + j, n_dst - 1), 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(n_runs, run_blocks),
-        in_specs=[pl.BlockSpec((C, 1, E), o_map),    # aliased dst (unread)
-                  pl.BlockSpec((C, 1, E), s_map)],
-        out_specs=pl.BlockSpec((C, 1, E), o_map),
-    )
-    return pl.pallas_call(
-        _gather_run_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(dst.shape, src.dtype),
-        input_output_aliases={3: 0},       # dst (4th operand) -> output
-        interpret=interpret,
-    )(src_starts.astype(jnp.int32), dst_starts.astype(jnp.int32),
-      run_lens.astype(jnp.int32), dst, src)
-
-
-def block_gather_runs(pool, slab0, src_starts, dst_starts, run_lens,
-                      run_blocks: int, interpret: bool = True) -> jnp.ndarray:
-    """Gather contiguous pool runs into a contiguous staging slab:
-    pool[:, s:s+l] -> slab[:, d:d+l] per run (the d2h half of the staged
-    swap path — one streaming DMA chain per run, then the whole slab
-    moves host-ward as ONE transfer instead of N scattered block copies).
-
-    pool: (C, nb, E) — the KV pool with leading (layer, k/v) dims
-    collapsed — or (C, nb, bs, H, D) with the SHARD AXIS (KV heads)
-    kept separate: under the mesh-sharded serving layout (DESIGN.md §9)
-    H is partitioned over ``model`` and this function runs per shard
-    inside ``shard_map``, flattening each shard's LOCAL heads into the
-    block element dim; the slab it stages therefore stays head-sharded
-    and crosses the host link as one transfer PER SHARD.
-    slab0: (C, n_slab, ...) matching pool's trailing layout, aliased
-    into the output."""
-    shape = slab0.shape
-    if pool.ndim > 3:
-        pool = pool.reshape(pool.shape[0], pool.shape[1], -1)
-        slab0 = slab0.reshape(shape[0], shape[1], -1)
-    out = _copy_runs_3d(pool, slab0, src_starts, dst_starts, run_lens,
-                        run_blocks, interpret)
-    return out.reshape(shape)
-
-
-def block_scatter_runs(slab, pool, src_starts, dst_starts, run_lens,
-                       run_blocks: int, interpret: bool = True) -> jnp.ndarray:
-    """Scatter a contiguous staging slab back into pool runs:
-    slab[:, s:s+l] -> pool[:, d:d+l] per run (the h2d half of the staged
-    swap path).  pool is aliased into the output — callers jit this with
-    the pool DONATED (see ``kernels/ops.py``) so the write is in place,
-    never an un-donated full-pool ``.at[].set`` copy.  Accepts the same
-    3-D collapsed or (C, nb, bs, H, D) shard-axis layouts as
-    ``block_gather_runs`` (slab and pool must match)."""
-    shape = pool.shape
-    if pool.ndim > 3:
-        slab = slab.reshape(slab.shape[0], slab.shape[1], -1)
-        pool = pool.reshape(shape[0], shape[1], -1)
-    out = _copy_runs_3d(slab, pool, src_starts, dst_starts, run_lens,
-                        run_blocks, interpret)
-    return out.reshape(shape)
+    src_pool: (nb_src, E); dst_pool: (nb_dst, E); ``run_blocks`` is the
+    static max run extent."""
+    return copy_runs(src_pool[None], dst_pool[None], src_starts, dst_starts,
+                     run_lens, run_blocks=run_blocks, interpret=interpret)[0]
 
 
 def runs_to_indices(runs: List[Tuple[int, int]]) -> List[int]:

@@ -60,7 +60,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
                                              "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
-                    block_k: int = 128, interpret: bool = True):
+                    block_k: int = 128, interpret: bool):
     """q, k, v: (B, H, T, D) (GQA pre-expanded).  Returns (B, H, T, D)."""
     B, H, T, D = q.shape
     S = k.shape[2]

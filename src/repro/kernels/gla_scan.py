@@ -67,7 +67,7 @@ def _kernel(q_ref, k_ref, v_ref, lw_ref, y_ref, s_out_ref, s_ref,
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def gla_scan_scalar(q, k, v, logw, *, chunk: int = 64,
-                    interpret: bool = True):
+                    interpret: bool):
     """q, k: (B, H, T, N); v: (B, H, T, P); logw: (B, H, T) scalar decay
     (<= 0).  Returns (y: (B, H, T, P), S: (B, H, N, P) fp32)."""
     B, H, T, N = q.shape

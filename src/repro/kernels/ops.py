@@ -1,8 +1,10 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-``interpret`` defaults to True because this container is CPU-only; on a
-real TPU deployment set ``repro.kernels.ops.INTERPRET = False`` (or pass
-explicitly) to run the compiled Mosaic kernels.
+The backend decides how the kernels run (``interpret_mode``): the Pallas
+interpreter on the CPU, compiled Mosaic kernels on a TPU.  The kernel
+modules themselves take ``interpret`` as a required argument, so a
+compile for a described (not attached) chip can ask for Mosaic while
+the process's backend is the CPU.
 """
 from __future__ import annotations
 
@@ -17,37 +19,47 @@ from repro.kernels import block_copy as _bc
 from repro.kernels import flash_attention as _fa
 from repro.kernels import paged_attention as _pa
 
-INTERPRET = True
+# backend -> are the Pallas kernels compiled by Mosaic (else interpreted)?
+_MOSAIC_COMPILED = {"cpu": False, "tpu": True}
+
+
+@functools.cache
+def interpret_mode() -> bool:
+    """True on the CPU (Pallas interpreter), False on a TPU (compiled
+    Mosaic).  Any other backend has no kernels here and is an error —
+    never a silent fallback to the interpreter."""
+    backend = jax.default_backend()
+    try:
+        return not _MOSAIC_COMPILED[backend]
+    except KeyError:
+        raise RuntimeError(f"no Pallas kernels for backend {backend!r}; "
+                           "run on a TPU, or on the CPU (interpret "
+                           "mode)") from None
 
 
 def paged_attention(q, k_pool, v_pool, block_tables, context_lens, scale,
-                    pages_per_compute_block: int = 1,
-                    interpret: bool | None = None):
+                    pages_per_compute_block: int = 1):
     return _pa.paged_attention(q, k_pool, v_pool, block_tables, context_lens,
                                scale,
                                pages_per_compute_block=pages_per_compute_block,
-                               interpret=INTERPRET if interpret is None else interpret)
+                               interpret=interpret_mode())
 
 
-def flash_attention(q, k, v, *, causal=True, block_q=128, block_k=128,
-                    interpret: bool | None = None):
+def flash_attention(q, k, v, *, causal=True, block_q=128, block_k=128):
     return _fa.flash_attention(q, k, v, causal=causal, block_q=block_q,
-                               block_k=block_k,
-                               interpret=INTERPRET if interpret is None else interpret)
+                               block_k=block_k, interpret=interpret_mode())
 
 
-def copy_blocks(src_pool, dst_pool, src_blocks, dst_blocks,
-                interpret: bool | None = None):
+def copy_blocks(src_pool, dst_pool, src_blocks, dst_blocks):
     """Per-block scattered copy (vLLM baseline data plane)."""
     return _bc.block_copy(src_pool, dst_pool,
                           jnp.asarray(src_blocks, jnp.int32),
                           jnp.asarray(dst_blocks, jnp.int32),
-                          interpret=INTERPRET if interpret is None else interpret)
+                          interpret=interpret_mode())
 
 
 def copy_block_runs(src_pool, dst_pool, runs: Sequence[Tuple[int, int]],
-                    dst_starts: Sequence[int],
-                    interpret: bool | None = None):
+                    dst_starts: Sequence[int]):
     """Grouped copy: runs[i]=(src_start, n_blocks) -> dst_starts[i]."""
     if not runs:
         return dst_pool
@@ -57,7 +69,7 @@ def copy_block_runs(src_pool, dst_pool, runs: Sequence[Tuple[int, int]],
     run_blocks = int(max(r[1] for r in runs))
     return _bc.block_copy_grouped(
         src_pool, dst_pool, src_starts, dsts, lens, run_blocks=run_blocks,
-        interpret=INTERPRET if interpret is None else interpret)
+        interpret=interpret_mode())
 
 
 def _next_pow2(n: int) -> int:
@@ -98,20 +110,20 @@ def _gather_swap_body(pool, src_starts, dst_starts, lens, *,
     """Shared gather body: stages pool runs into a zeroed slab.  The slab
     keeps the (bs, H, D) block element axes SEPARATE (5-D) so the head
     axis survives as the shard axis under the mesh layout — each shard
-    flattens only its local heads inside ``block_gather_runs``."""
+    copies its local heads."""
     L, K, nb, bs, H, D = pool.shape
     slab0 = jnp.zeros((L * K, n_slab, bs, H, D), pool.dtype)
-    return _bc.block_gather_runs(pool.reshape(L * K, nb, bs, H, D), slab0,
-                                 src_starts, dst_starts, lens,
-                                 run_blocks=run_blocks, interpret=interpret)
+    return _bc.copy_runs(pool.reshape(L * K, nb, bs, H, D), slab0,
+                         src_starts, dst_starts, lens,
+                         run_blocks=run_blocks, interpret=interpret)
 
 
 def _scatter_swap_body(pool, slab, src_starts, dst_starts, lens, *,
                        run_blocks: int, interpret: bool):
     L, K, nb, bs, H, D = pool.shape
-    p5 = _bc.block_scatter_runs(slab, pool.reshape(L * K, nb, bs, H, D),
-                                src_starts, dst_starts, lens,
-                                run_blocks=run_blocks, interpret=interpret)
+    p5 = _bc.copy_runs(slab, pool.reshape(L * K, nb, bs, H, D),
+                       src_starts, dst_starts, lens,
+                       run_blocks=run_blocks, interpret=interpret)
     return p5.reshape(pool.shape)
 
 
@@ -131,29 +143,27 @@ def _gather_swap_sharded_impl(pool, src_starts, dst_starts, lens, *,
     kernel over its local heads, producing a head-sharded slab — the d2h
     leg is then one transfer per shard, each 1/M the single-device
     bytes."""
-    from jax.experimental.shard_map import shard_map
     from repro.models.sharding import pool_pspec, rep_pspec, slab_pspec
     body = functools.partial(_gather_swap_body, n_slab=n_slab,
                              run_blocks=run_blocks, interpret=interpret)
     rep = rep_pspec()
-    return shard_map(body, mesh=mesh,
-                     in_specs=(pool_pspec(), rep, rep, rep),
-                     out_specs=slab_pspec(),
-                     check_rep=False)(pool, src_starts, dst_starts, lens)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(pool_pspec(), rep, rep, rep),
+                         out_specs=slab_pspec(),
+                         check_vma=False)(pool, src_starts, dst_starts, lens)
 
 
 def _scatter_swap_sharded_impl(pool, slab, src_starts, dst_starts, lens, *,
                                run_blocks: int, interpret: bool, mesh):
-    from jax.experimental.shard_map import shard_map
     from repro.models.sharding import pool_pspec, rep_pspec, slab_pspec
     body = functools.partial(_scatter_swap_body, run_blocks=run_blocks,
                              interpret=interpret)
     rep = rep_pspec()
-    return shard_map(body, mesh=mesh,
-                     in_specs=(pool_pspec(), slab_pspec(), rep, rep, rep),
-                     out_specs=pool_pspec(),
-                     check_rep=False)(pool, slab, src_starts, dst_starts,
-                                      lens)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(pool_pspec(), slab_pspec(), rep, rep, rep),
+                         out_specs=pool_pspec(),
+                         check_vma=False)(pool, slab, src_starts, dst_starts,
+                                          lens)
 
 
 # jitted sharded variants: same donation / bucketing contract as the
@@ -168,8 +178,7 @@ _scatter_swap_sharded = jax.jit(
     donate_argnums=(0,))
 
 
-def gather_swap_runs(pool, runs: Sequence[Tuple[int, int]],
-                     interpret: bool | None = None, mesh=None):
+def gather_swap_runs(pool, runs: Sequence[Tuple[int, int]], mesh=None):
     """Run-coalesced staged swap-out gather: copy the pool blocks named by
     ``runs`` [(start, n_blocks)] into one contiguous device staging slab
     (one grouped kernel over runs), so the d2h leg is a SINGLE transfer
@@ -184,16 +193,16 @@ def gather_swap_runs(pool, runs: Sequence[Tuple[int, int]],
     transfer per shard)."""
     assert runs, "gather_swap_runs needs at least one run"
     src, dst, lens, _, n_slab, run_blocks = _pad_runs(runs)
-    interp = INTERPRET if interpret is None else interpret
     fn = _gather_swap if mesh is None else functools.partial(
         _gather_swap_sharded, mesh=mesh)
     slab = fn(pool, jnp.asarray(src), jnp.asarray(dst), jnp.asarray(lens),
-              n_slab=n_slab, run_blocks=run_blocks, interpret=interp)
+              n_slab=n_slab, run_blocks=run_blocks,
+              interpret=interpret_mode())
     return slab, int(sum(n for _, n in runs))
 
 
 def scatter_swap_runs(pool, slab, runs: Sequence[Tuple[int, int]],
-                      interpret: bool | None = None, mesh=None):
+                      mesh=None):
     """Run-coalesced staged swap-in scatter: copy slab blocks [0, total)
     into the pool blocks named by ``runs``.  pool is DONATED — the write
     is in place and the caller MUST rebind its reference to the returned
@@ -204,12 +213,12 @@ def scatter_swap_runs(pool, slab, runs: Sequence[Tuple[int, int]],
     assert runs, "scatter_swap_runs needs at least one run"
     src, dst, lens, _, n_slab, run_blocks = _pad_runs(runs)
     assert slab.shape[1] == n_slab, (slab.shape, n_slab)
-    interp = INTERPRET if interpret is None else interpret
     fn = _scatter_swap if mesh is None else functools.partial(
         _scatter_swap_sharded, mesh=mesh)
     # gather offsets are the slab side here: slab[dst] -> pool[src]
     return fn(pool, slab, jnp.asarray(dst), jnp.asarray(src),
-              jnp.asarray(lens), run_blocks=run_blocks, interpret=interp)
+              jnp.asarray(lens), run_blocks=run_blocks,
+              interpret=interpret_mode())
 
 
 def swap_gather_cache_size() -> int:
@@ -261,14 +270,16 @@ def insert_prefill_cache_size() -> int:
 # ---------------------------------------------------------------------------
 
 
-def _zeros_carry(shape, mesh):
-    """Zeroed prefill carry, head-sharded when ``mesh`` is given."""
-    z = jnp.zeros(shape, jnp.bfloat16)
+def _zeros_carry(shape, params, mesh):
+    """Zeroed prefill carry on the params' device, head-sharded when
+    ``mesh`` is given."""
     if mesh is None:
-        return z
-    from jax.sharding import NamedSharding
-    from repro.models.sharding import carry_pspec
-    return jax.device_put(z, NamedSharding(mesh, carry_pspec()))
+        sharding = jax.tree.leaves(params)[0].sharding
+    else:
+        from jax.sharding import NamedSharding
+        from repro.models.sharding import carry_pspec
+        sharding = NamedSharding(mesh, carry_pspec())
+    return jnp.zeros(shape, jnp.bfloat16, device=sharding)
 
 
 @functools.partial(jax.jit, static_argnames=("n_new",))
@@ -316,8 +327,8 @@ def prefill_chunk(params, tokens: Sequence[int], k_carry, v_carry,
     if k_carry is None:
         s_pad = _next_pow2(need)
         shape = (cfg.n_layers, s_pad, cfg.n_kv_heads, cfg.resolved_head_dim)
-        k_carry = _zeros_carry(shape, mesh)
-        v_carry = _zeros_carry(shape, mesh)
+        k_carry = _zeros_carry(shape, params, mesh)
+        v_carry = _zeros_carry(shape, params, mesh)
     elif k_carry.shape[1] < need:
         s_pad = _next_pow2(need)
         k_carry = _grow_carry(k_carry, n_new=s_pad)
@@ -376,9 +387,8 @@ def seed_prefill_carry(pool, block_ids: Sequence[int], start_tokens: int,
     return _seed_carry(pool, jnp.asarray(blocks))
 
 
-def gla_scan_scalar(q, k, v, logw, *, chunk=64, interpret: bool | None = None):
+def gla_scan_scalar(q, k, v, logw, *, chunk=64):
     """Chunked scalar-decay gated linear attention (Mamba2/SSD hot path)."""
     from repro.kernels import gla_scan as _gla
-    return _gla.gla_scan_scalar(
-        q, k, v, logw, chunk=chunk,
-        interpret=INTERPRET if interpret is None else interpret)
+    return _gla.gla_scan_scalar(q, k, v, logw, chunk=chunk,
+                                interpret=interpret_mode())

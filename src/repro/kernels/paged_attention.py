@@ -14,8 +14,11 @@ one-page-per-step baseline.  A ragged final tile is padded with page 0 and
 masked by the context length (padded token positions are always
 >= context_len, so their logits are NEG_INF).
 
-Grid: (B, Hkv, n_tiles); accumulators live in VMEM scratch and the output
-page is written on the last grid step.
+Grid: (B, n_tiles).  Each grid step fetches whole pages — block
+``(1, bs, Hkv, D)``, whose two minor dims equal the pool's, as Mosaic's
+tiling rule requires (a one-head ``(1, bs, 1, D)`` block is refused for
+v5e) — and loops over the KV heads inside the kernel.  Accumulators live
+in per-head VMEM scratch and the output is written on the last grid step.
 """
 from __future__ import annotations
 
@@ -30,13 +33,13 @@ NEG_INF = -1e30
 
 
 def _kernel(bt_ref, ctx_ref, q_ref, *refs,
-            bs: int, scale: float, n_tiles: int, ppcb: int):
+            bs: int, hkv: int, scale: float, n_tiles: int, ppcb: int):
     k_refs = refs[:ppcb]
     v_refs = refs[ppcb:2 * ppcb]
     o_ref = refs[2 * ppcb]
     m_ref, l_ref, acc_ref = refs[2 * ppcb + 1:2 * ppcb + 4]
     b = pl.program_id(0)
-    i = pl.program_id(2)
+    i = pl.program_id(1)
 
     @pl.when(i == 0)
     def _init():
@@ -45,42 +48,45 @@ def _kernel(bt_ref, ctx_ref, q_ref, *refs,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     ctx = ctx_ref[b]
-    q = q_ref[0, 0].astype(jnp.float32)                 # (group, D)
-    # ppcb pages fused into one (ppcb*bs, D) KV tile
-    k = jnp.concatenate([r[0, :, 0, :] for r in k_refs],
-                        axis=0).astype(jnp.float32)
-    v = jnp.concatenate([r[0, :, 0, :] for r in v_refs],
-                        axis=0).astype(jnp.float32)
-
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
     tile = ppcb * bs
     token_ids = i * tile + jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1)
     mask = token_ids < ctx                               # (1, tile)
-    s = jnp.where(mask, s, NEG_INF)
+    for h in range(hkv):
+        q = q_ref[0, h].astype(jnp.float32)             # (group, D)
+        # ppcb pages fused into one (ppcb*bs, D) KV tile of head h
+        k = jnp.concatenate([r[0, :, h, :] for r in k_refs],
+                            axis=0).astype(jnp.float32)
+        v = jnp.concatenate([r[0, :, h, :] for r in v_refs],
+                            axis=0).astype(jnp.float32)
 
-    m_prev = m_ref[...][:, 0]                            # (group,)
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new[:, None])                      # (group, tile)
-    l_new = l_ref[...][:, 0] * alpha + jnp.sum(p, axis=1)
-    acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    m_ref[...] = m_new[:, None]
-    l_ref[...] = l_new[:, None]
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        s = jnp.where(mask, s, NEG_INF)
+
+        m_prev = m_ref[h][:, 0]                          # (group,)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new[:, None])                  # (group, tile)
+        l_new = l_ref[h][:, 0] * alpha + jnp.sum(p, axis=1)
+        acc_ref[h] = acc_ref[h] * alpha[:, None] + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[h] = m_new[:, None]
+        l_ref[h] = l_new[:, None]
 
     @pl.when(i == n_tiles - 1)
     def _finish():
-        l = jnp.maximum(l_ref[...], 1e-30)               # guard ctx == 0
-        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        for h in range(hkv):
+            l = jnp.maximum(l_ref[h], 1e-30)             # guard ctx == 0
+            o_ref[0, h] = (acc_ref[h] / l).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("scale",
                                              "pages_per_compute_block",
                                              "interpret"))
 def paged_attention(q, k_pool, v_pool, block_tables, context_lens,
-                    scale: float, pages_per_compute_block: int = 1,
-                    interpret: bool = True) -> jnp.ndarray:
+                    scale: float, pages_per_compute_block: int = 1, *,
+                    interpret: bool) -> jnp.ndarray:
     """q: (B, Hq, D); k_pool/v_pool: (nb, bs, Hkv, D);
     block_tables: (B, n_pages) int32; context_lens: (B,) int32.
     ``pages_per_compute_block``: KV pages streamed per grid step.
@@ -100,35 +106,32 @@ def paged_attention(q, k_pool, v_pool, block_tables, context_lens,
     npp = n_tiles * ppcb
     flat_bt = block_tables.reshape(-1).astype(jnp.int32)
 
-    def q_map(b, h, i, bt, ctx):
-        return (b, h, 0, 0)
+    def q_map(b, i, bt, ctx):
+        return (b, 0, 0, 0)
 
     def kv_map(j):
-        def index_map(b, h, i, bt, ctx):
-            return (bt[b * npp + i * ppcb + j], 0, h, 0)
+        def index_map(b, i, bt, ctx):
+            return (bt[b * npp + i * ppcb + j], 0, 0, 0)
         return index_map
-
-    def o_map(b, h, i, bt, ctx):
-        return (b, h, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, Hkv, n_tiles),
+        grid=(B, n_tiles),
         in_specs=(
-            [pl.BlockSpec((1, 1, group, D), q_map)]
-            + [pl.BlockSpec((1, bs, 1, D), kv_map(j)) for j in range(ppcb)]
-            + [pl.BlockSpec((1, bs, 1, D), kv_map(j)) for j in range(ppcb)]
+            [pl.BlockSpec((1, Hkv, group, D), q_map)]
+            + [pl.BlockSpec((1, bs, Hkv, D), kv_map(j)) for j in range(ppcb)]
+            + [pl.BlockSpec((1, bs, Hkv, D), kv_map(j)) for j in range(ppcb)]
         ),
-        out_specs=pl.BlockSpec((1, 1, group, D), o_map),
+        out_specs=pl.BlockSpec((1, Hkv, group, D), q_map),
         scratch_shapes=[
-            pltpu.VMEM((group, 1), jnp.float32),
-            pltpu.VMEM((group, 1), jnp.float32),
-            pltpu.VMEM((group, D), jnp.float32),
+            pltpu.VMEM((Hkv, group, 1), jnp.float32),
+            pltpu.VMEM((Hkv, group, 1), jnp.float32),
+            pltpu.VMEM((Hkv, group, D), jnp.float32),
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_kernel, bs=bs, scale=scale, n_tiles=n_tiles,
-                          ppcb=ppcb),
+        functools.partial(_kernel, bs=bs, hkv=Hkv, scale=scale,
+                          n_tiles=n_tiles, ppcb=ppcb),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, group, D), q.dtype),
         interpret=interpret,

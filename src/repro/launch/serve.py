@@ -71,18 +71,37 @@ import random
 
 
 def _build_real_bundle(arch: str, seed: int):
+    """Random weights from ``seed`` for ``arch``.  The platform picks the
+    size: the published widths on an accelerator, the reduced
+    same-family config on the CPU (where the kernels are interpreted)."""
     import jax
 
-    from repro.configs import get_smoke_config
+    from repro.configs import get_config, get_smoke_config
     from repro.models import transformer as T
     from repro.models.paged import supports_paged
-    cfg = get_smoke_config(arch)
+    cfg = (get_smoke_config(arch) if jax.default_backend() == "cpu"
+           else get_config(arch))
     if not supports_paged(cfg):
         raise SystemExit(
             f"{arch}: real-mode serving needs a uniform GQA arch "
             "(paged pool path); use sim mode for this family")
     params = T.init_params(cfg, jax.random.PRNGKey(seed))
+    leaves = jax.tree.leaves(params)
+    print(f"model {cfg.name}: {cfg.n_layers} layers, d_model "
+          f"{cfg.d_model}, {cfg.n_heads}/{cfg.n_kv_heads} heads x "
+          f"{cfg.resolved_head_dim}, d_ff {cfg.d_ff}, vocab "
+          f"{cfg.vocab_size}; params {sum(x.size for x in leaves)} "
+          f"({sum(x.nbytes for x in leaves)} bytes, "
+          f"{sorted({str(x.dtype) for x in leaves})})", flush=True)
     return {"cfg": cfg, "params": params}
+
+
+def replica_devices(n: int) -> list:
+    """The device of each of ``n`` engine replicas: replica ``i`` on
+    ``jax.devices()[i % len(jax.devices())]``."""
+    import jax
+    devices = jax.devices()
+    return [devices[i % len(devices)] for i in range(n)]
 
 
 def validate_event_log(path: str) -> int:
@@ -306,7 +325,10 @@ def run_online(args) -> dict:
     result = {**m.summary(), "slo": m.slo_summary(), **engine.swap.stats()}
     if args.chaos:
         result["faults_fired"] = dict(engine.faults.fired)
-    print(f"online[{policy}] " + json.dumps(m.summary()))
+    # TTFT/TBT come from the engine's sim clock (the cost model), even
+    # in real mode: they are never a time measured on the device
+    print(f"online[{policy}] sim-clock[{engine.iter_cost.hw.name}] "
+          + json.dumps(m.summary()))
     print("slo " + json.dumps(m.slo_summary()))
     if args.chaos:
         print("chaos " + json.dumps({
@@ -382,7 +404,8 @@ def run_replay(args) -> dict:
                 model_bundle=model)
             m = eng.run()
             results[pol] = {**m.summary(), **eng.swap.stats()}
-            print(pol, json.dumps(m.summary(), indent=None))
+            print(pol, f"sim-clock[{eng.iter_cost.hw.name}]",
+                  json.dumps(m.summary(), indent=None))
     else:
         convs = sample_conversations(args.conversations, rate_req_s=args.rate,
                                      seed=args.seed)
@@ -432,6 +455,7 @@ def run_serve(args) -> dict:
 
     files = []
     engines = []
+    devices = replica_devices(n) if args.real else [None] * n
     for i in range(n):
         sink = None
         if args.events:
@@ -445,7 +469,8 @@ def run_serve(args) -> dict:
         engines.append(ServingEngine(cfg, model_bundle=model,
                                      event_sink=sink,
                                      stream_tokens=bool(args.stream
-                                                        and args.real)))
+                                                        and args.real),
+                                     device=devices[i]))
 
     async def _run():
         srv = FrontendServer(engines, host=args.host, port=args.port)
@@ -459,7 +484,7 @@ def run_serve(args) -> dict:
             await srv.close()
 
     try:
-        asyncio.get_event_loop().run_until_complete(_run())
+        asyncio.run(_run())
     except KeyboardInterrupt:
         print("frontend: interrupted, shutting down")
     finally:
@@ -470,10 +495,12 @@ def run_serve(args) -> dict:
 
 def main() -> None:
     from repro.core.policies import POLICIES
+    from repro.launch.compile_cache import enable_compile_cache
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-1.5b")
     ap.add_argument("--real", action="store_true",
-                    help="reduced real model + paged pool (CPU)")
+                    help="real model + paged pool: published widths on "
+                         "an accelerator, the reduced config on the CPU")
     ap.add_argument("--policy", action="append", default=None,
                     choices=sorted(POLICIES))
     ap.add_argument("--conversations", type=int, default=100)
@@ -525,6 +552,7 @@ def main() -> None:
     ap.add_argument("--port", type=int, default=8471,
                     help="--serve listen port (0 picks a free one)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.prefix_cache:
         args.real = True               # the cache lives on the real pool

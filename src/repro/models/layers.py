@@ -111,8 +111,8 @@ def init_embedding(key, vocab: int, d_model: int):
     return {"table": jax.random.normal(key, (vocab, d_model), jnp.float32) * 0.02}
 
 
-def embed(params, tokens):
-    return params["table"].astype(COMPUTE_DTYPE)[tokens]
+def embed(params, tokens, dtype=COMPUTE_DTYPE):
+    return params["table"].astype(dtype)[tokens]
 
 
 def unembed(params, x, table=None):

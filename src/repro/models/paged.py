@@ -295,7 +295,6 @@ def _sharded_device_step(params, pool, block_tables, context_lens,
     bit-identical to the single-device step (mesh (1,1) degenerates to
     it exactly).
     """
-    from jax.experimental.shard_map import shard_map
     from repro.models.sharding import (pool_pspec, rep_pspec,
                                        serving_param_pspecs)
     n = mesh.shape["model"]
@@ -303,12 +302,12 @@ def _sharded_device_step(params, pool, block_tables, context_lens,
     body = functools.partial(_device_step_core, cfg=local_cfg,
                              axis_name="model", n_shards=n)
     rep = rep_pspec()
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(serving_param_pspecs(params), pool_pspec(), rep, rep,
                   rep, rep, rep, rep),
         out_specs=(rep, pool_pspec(), rep, rep),
-        check_rep=False,       # pallas_call has no replication rule
+        check_vma=False,       # pallas_call has no replication rule
     )(params, pool, block_tables, context_lens, tokens, active, keys,
       sampling)
 
@@ -423,19 +422,18 @@ def _sharded_prefill_chunk(params, tokens, k_carry, v_carry, prefix_len,
     head-sharded over ``"model"``, per-shard attention covers local
     heads only, and the head-concat all-gather before the replicated
     ``wo`` keeps the logits bit-identical to ``prefill_kv_chunk``."""
-    from jax.experimental.shard_map import shard_map
     from repro.models.sharding import (carry_pspec, rep_pspec,
                                        serving_param_pspecs)
     local_cfg = shard_local_config(cfg, mesh.shape["model"])
     body = functools.partial(_prefill_chunk_core, cfg=local_cfg,
                              axis_name="model")
     rep = rep_pspec()
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(serving_param_pspecs(params), rep, carry_pspec(),
                   carry_pspec(), rep, rep),
         out_specs=(rep, carry_pspec(), carry_pspec()),
-        check_rep=False,
+        check_vma=False,
     )(params, tokens, k_carry, v_carry, prefix_len, chunk_len)
 
 

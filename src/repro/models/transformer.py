@@ -369,10 +369,11 @@ def whisper_decode_step(params, cfg: ModelConfig, caches: WhisperCaches,
 # Sequence-mode forward (train / prefill) for decoder-only stacks
 # ---------------------------------------------------------------------------
 
-def _embed_inputs(params, cfg: ModelConfig, tokens, extra_embeds):
+def _embed_inputs(params, cfg: ModelConfig, tokens, extra_embeds,
+                  dtype=CD):
     """tokens: (B, T_text); extra_embeds: (B, T_img, d_embed) or None.
     VLM: image embeds are projected and *prepended* to the text tokens."""
-    x = L.embed(params["embed"], tokens)
+    x = L.embed(params["embed"], tokens, dtype)
     if extra_embeds is not None and "mm_proj" in params:
         img = extra_embeds.astype(CD) @ params["mm_proj"]["w"].astype(CD)
         x = jnp.concatenate([img, x], axis=1)
@@ -383,8 +384,11 @@ def _embed_inputs(params, cfg: ModelConfig, tokens, extra_embeds):
 
 def forward_seq(params, cfg: ModelConfig, tokens, extra_embeds=None,
                 remat: bool = False, encoder_frames=None,
-                last_only: bool = False, return_hidden: bool = False):
+                last_only: bool = False, return_hidden: bool = False,
+                compute_dtype=CD):
     """Full-sequence forward.  Returns (logits, caches_stacked, aux_loss).
+    ``compute_dtype``: activation dtype (bf16 as served; a float32
+    reference passes ``jnp.float32``).
 
     caches_stacked layouts match the per-pattern decode caches but with
     S == T (prefill length); ``init_caches``+``write`` extends them.
@@ -397,7 +401,8 @@ def forward_seq(params, cfg: ModelConfig, tokens, extra_embeds=None,
         return logits, caches, jnp.float32(0.0)
 
     pat = _pattern(cfg)
-    x, positions = _embed_inputs(params, cfg, tokens, extra_embeds)
+    x, positions = _embed_inputs(params, cfg, tokens, extra_embeds,
+                                 compute_dtype)
     aux_total = jnp.float32(0.0)
     caches = None
 

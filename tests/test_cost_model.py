@@ -1,11 +1,14 @@
 """I/O cost model + trace generators."""
+from types import SimpleNamespace
+
 import numpy as np
+import pytest
 
 from repro.data.priority import PriorityTrace
 from repro.data.sharegpt import sample_conversations, trace_stats
 from repro.io.cost_model import (A10_PCIE4, IterationCostModel,
                                  dispatch_time_us, exec_time_us,
-                                 transfer_time_us)
+                                 serving_hardware, transfer_time_us)
 
 
 def test_small_transfers_are_dispatch_bound():
@@ -60,3 +63,23 @@ def test_priority_trace_reproducible():
         t1.step(ids, ids[:4])
         t2.step(ids, ids[:4])
     assert all(t1.priority(i) == t2.priority(i) for i in ids)
+
+
+@pytest.mark.parametrize("platform,kind,expect", [
+    ("cpu", "cpu", "preset"),                 # the interpret-mode test path
+    ("tpu", "TPU v5 lite", "TPUv5e-host"),
+    ("tpu", "TPU v9 imaginary", ValueError),  # unknown kind: no default
+    ("gpu", "NVIDIA A10", ValueError),
+])
+def test_serving_hardware_by_device_kind(platform, kind, expect):
+    """A real-mode engine's cost model comes from the device it serves
+    on: the configured preset on the CPU, the ``DEVICE_KINDS`` entry on
+    an accelerator, and an error for a kind the table lacks."""
+    dev = SimpleNamespace(platform=platform, device_kind=kind)
+    if expect is ValueError:
+        with pytest.raises(ValueError, match="no cost model"):
+            serving_hardware(dev, A10_PCIE4)
+    elif expect == "preset":
+        assert serving_hardware(dev, A10_PCIE4) is A10_PCIE4
+    else:
+        assert serving_hardware(dev, A10_PCIE4).name == expect

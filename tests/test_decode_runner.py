@@ -11,7 +11,7 @@ from repro.cache.paged import PagedPools, PoolSpec
 from repro.configs import get_smoke_config
 from repro.core.decode_runner import (DecodeRequestView, DecodeRunner,
                                       next_pow2)
-from repro.kernels.paged_attention import paged_attention
+from repro.kernels.ops import paged_attention
 from repro.kernels.ref import paged_attention_ref
 from repro.models import transformer as T
 from repro.models.paged import paged_decode_step

@@ -1,13 +1,22 @@
-"""Pallas kernels vs pure-jnp oracles: shape/dtype sweeps (interpret=True)."""
+"""Pallas kernels vs pure-jnp oracles: shape/dtype sweeps, in the mode
+the backend selects (the interpreter on the CPU)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.block_copy import block_copy, block_copy_grouped
-from repro.kernels.flash_attention import flash_attention
-from repro.kernels.paged_attention import paged_attention
+from repro.kernels.ops import (copy_block_runs, copy_blocks, flash_attention,
+                               gla_scan_scalar, interpret_mode,
+                               paged_attention)
 from repro.kernels.ref import block_copy_ref, mha_ref, paged_attention_ref
+
+
+def test_backend_selects_interpret_mode():
+    """The CPU backend runs the Pallas interpreter; a TPU would compile
+    Mosaic kernels (``tests/test_tpu_compile.py`` compiles them for a
+    described v5e)."""
+    assert jax.default_backend() == "cpu"
+    assert interpret_mode() is True
 
 
 @pytest.mark.parametrize("B,Hq,Hkv,D,bs,npages", [
@@ -16,6 +25,9 @@ from repro.kernels.ref import block_copy_ref, mha_ref, paged_attention_ref
     (2, 16, 16, 128, 16, 3),   # MHA wide head
     (2, 12, 2, 128, 32, 2),    # qwen2-like, bigger block
     (1, 8, 1, 64, 16, 8),      # MQA
+    (2, 12, 2, 128, 16, 4),    # qwen2-1.5b widths (group 6, bs 16)
+    (3, 8, 4, 128, 16, 5),     # Hkv 4, group 2
+    (2, 32, 8, 128, 16, 3),    # Hkv 8, group 4
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_paged_attention_sweep(B, Hq, Hkv, D, bs, npages, dtype):
@@ -41,10 +53,11 @@ def test_paged_attention_sweep(B, Hq, Hkv, D, bs, npages, dtype):
 
 @pytest.mark.parametrize("ppcb", [1, 2, 4])
 @pytest.mark.parametrize("npages", [3, 4, 5, 8])
-def test_paged_attention_multipage_tiles(ppcb, npages):
+@pytest.mark.parametrize("Hq,Hkv,D", [(8, 2, 64), (12, 2, 128)])
+def test_paged_attention_multipage_tiles(ppcb, npages, Hq, Hkv, D):
     """pages_per_compute_block > 1 streams several KV pages per grid step;
     npages not divisible by ppcb exercises the ragged final tile."""
-    B, Hq, Hkv, D, bs = 2, 8, 2, 64, 16
+    B, bs = 2, 16
     nb = npages * B + 3
     ks = jax.random.split(jax.random.PRNGKey(42), 4)
     q = jax.random.normal(ks[0], (B, Hq, D))
@@ -82,7 +95,7 @@ def test_block_copy_sweep(n_src, n_dst, n_copy, E, dtype):
     rng = np.random.RandomState(1)
     si = jnp.asarray(rng.choice(n_src, n_copy, replace=False), jnp.int32)
     di = jnp.asarray(rng.choice(n_dst, n_copy, replace=False), jnp.int32)
-    out = block_copy(src, dst, si, di)
+    out = copy_blocks(src, dst, si, di)
     ref = block_copy_ref(src, dst, si, di)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
@@ -91,6 +104,7 @@ def test_block_copy_sweep(n_src, n_dst, n_copy, E, dtype):
     [(0, 4), (10, 2)],
     [(3, 1)],
     [(0, 8), (8, 8), (20, 4)],
+    [(1, 7), (12, 5), (24, 3)],     # lengths cut into several DMAs
 ])
 def test_block_copy_grouped_sweep(runs):
     key = jax.random.PRNGKey(7)
@@ -104,12 +118,7 @@ def test_block_copy_grouped_sweep(runs):
     ref = dst
     for (s, n), ds in zip(runs, dst_starts):
         ref = ref.at[ds:ds + n].set(src[s:s + n])
-    out = block_copy_grouped(
-        src, dst,
-        jnp.asarray([r[0] for r in runs], jnp.int32),
-        jnp.asarray(dst_starts, jnp.int32),
-        jnp.asarray([r[1] for r in runs], jnp.int32),
-        run_blocks=max(r[1] for r in runs))
+    out = copy_block_runs(src, dst, runs, dst_starts)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
 
@@ -145,7 +154,6 @@ def test_flash_attention_sweep(B, H, T, D, bq, bk, causal, dtype):
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_gla_scan_scalar_sweep(B, H, T, N, P, chunk, dtype):
-    from repro.kernels.gla_scan import gla_scan_scalar
     from repro.models.gla import gla_scan_ref
     ks = jax.random.split(jax.random.PRNGKey(1), 4)
     q = (jax.random.normal(ks[0], (B, H, T, N)) * 0.5).astype(dtype)

@@ -77,3 +77,23 @@ def test_checkpoint_optimizer_state(tmp_path):
     np.testing.assert_array_equal(
         np.asarray(jax.tree.leaves(opt.mu)[0]),
         np.asarray(jax.tree.leaves(restored.mu)[0]))
+
+
+def test_compile_cache_dir_env_wins_else_fixed_checkout_dir(monkeypatch):
+    """``JAX_COMPILATION_CACHE_DIR`` is used as set, with no directory
+    set in code; without it the cache sits at one fixed directory in
+    the checkout."""
+    from repro.launch import compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/jc")
+        jax.config.update("jax_compilation_cache_dir", "/elsewhere/jc")
+        assert compile_cache.enable_compile_cache() == "/elsewhere/jc"
+        assert jax.config.jax_compilation_cache_dir == "/elsewhere/jc"
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = compile_cache.enable_compile_cache()
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert path == os.path.join(root, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -268,3 +268,44 @@ print("SLAB_ROUND_TRIP_OK")
 def test_per_shard_slab_round_trip_bit_exact():
     out = _run_forced(SLAB_ROUND_TRIP)
     assert "SLAB_ROUND_TRIP_OK" in out
+
+
+REPLICA_PLACEMENT = r"""
+import jax
+import numpy as np
+from repro.core import EngineConfig, SamplingParams, ServingEngine
+from repro.launch.serve import _build_real_bundle, replica_devices
+
+bundle = _build_real_bundle("qwen2-1.5b", 0)
+assert bundle["cfg"].name == "qwen2-1.5b-smoke"     # the CPU's size
+devices = replica_devices(6)
+assert devices == [jax.devices()[i % 4] for i in range(6)]
+cfg = EngineConfig(mode="real", num_gpu_blocks=32, num_cpu_blocks=64,
+                   max_running=2, max_batch=2).with_policy("fastswitch")
+prompt = [(5 * i + 1) % bundle["cfg"].vocab_size for i in range(37)]
+streams = []
+for dev in devices[:4]:
+    eng = ServingEngine(cfg, model_bundle=bundle, stream_tokens=True,
+                        device=dev)
+    eng.add_request(prompt, SamplingParams(max_tokens=6))
+    toks = []
+    while eng.has_work():
+        for out in eng.step():
+            toks += out.token_ids or []
+    eng.shutdown()
+    r = eng.runner
+    placed = [eng.pools.gpu, r._bt, r._ctx, r._tok, r._active, r._keys,
+              r._sampling] + jax.tree.leaves(r._params)
+    assert all(x.devices() == {dev} for x in placed), dev
+    streams.append(toks)
+assert len(streams[0]) == 6 and all(s == streams[0] for s in streams)
+print("REPLICA_PLACEMENT_OK")
+"""
+
+
+def test_replicas_each_serve_from_their_own_device():
+    """``run_serve``'s placement: replica i keeps its pool, params and
+    decode state on ``jax.devices()[i % n]`` and serves the same tokens
+    as every other replica."""
+    out = _run_forced(REPLICA_PLACEMENT)
+    assert "REPLICA_PLACEMENT_OK" in out

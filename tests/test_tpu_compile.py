@@ -1,0 +1,91 @@
+"""Compile the served path's Pallas kernels for a described TPU v5e.
+
+Interpret mode (what every other kernel test runs on the CPU) never
+checks Mosaic's tiling rules or VMEM limits; the TPU compiler installed
+with JAX does, for a chip that is described and not attached.  Shapes
+are Qwen2-1.5B's serving widths: 28 layers, 2 KV heads, head_dim 128,
+12 query heads, block size 16, a bf16 pool and float32 queries.
+
+The topology is described inside a module-scoped fixture, never while
+a module is imported: only one process at a time may load the TPU
+library, so the worker that runs this file loads it and the others
+never try.
+"""
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import ops
+from repro.kernels.paged_attention import paged_attention
+
+L, HQ, HKV, D, BS = 28, 12, 2, 128, 16
+NB = 1024                       # pool blocks (16k tokens)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but can never be read back without the chip: keep the cache off
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", enabled)
+
+
+def _spec(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+@pytest.mark.parametrize("ppcb", [1, 4])
+def test_paged_attention_compiles_for_v5e(one_chip, ppcb):
+    B, n_pages = 8, 128
+    fn = functools.partial(paged_attention, scale=D ** -0.5,
+                           pages_per_compute_block=ppcb, interpret=False)
+    compiled = jax.jit(fn).lower(
+        _spec((B, HQ, D), jnp.float32, one_chip),
+        _spec((NB, BS, HKV, D), jnp.bfloat16, one_chip),
+        _spec((NB, BS, HKV, D), jnp.bfloat16, one_chip),
+        _spec((B, n_pages), jnp.int32, one_chip),
+        _spec((B,), jnp.int32, one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("n_slab,run_blocks", [(64, 8), (256, 256)])
+def test_staged_gather_compiles_for_v5e(one_chip, n_slab, run_blocks):
+    n_runs = 8
+    compiled = ops._gather_swap.lower(
+        _spec((L, 2, NB, BS, HKV, D), jnp.bfloat16, one_chip),
+        _spec((n_runs,), jnp.int32, one_chip),
+        _spec((n_runs,), jnp.int32, one_chip),
+        _spec((n_runs,), jnp.int32, one_chip),
+        n_slab=n_slab, run_blocks=run_blocks, interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_donated_staged_scatter_compiles_for_v5e(one_chip):
+    n_runs, n_slab, run_blocks = 8, 64, 8
+    compiled = ops._scatter_swap.lower(
+        _spec((L, 2, NB, BS, HKV, D), jnp.bfloat16, one_chip),
+        _spec((L * 2, n_slab, BS, HKV, D), jnp.bfloat16, one_chip),
+        _spec((n_runs,), jnp.int32, one_chip),
+        _spec((n_runs,), jnp.int32, one_chip),
+        _spec((n_runs,), jnp.int32, one_chip),
+        run_blocks=run_blocks, interpret=False).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the pool is donated and aliased through the kernel: written in
+    # place, never copied whole
+    assert "input_output_alias" in text
