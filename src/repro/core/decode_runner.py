@@ -77,6 +77,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import tracing
 from repro.kernels import ops
 from repro.models.paged import (paged_decode_step_device,
                                 paged_decode_step_device_sharded,
@@ -197,6 +198,7 @@ class DecodeRunner:
         # in-flight chunked prefills, keyed by rid (DESIGN.md §5)
         self._prefills: Dict[int, _PrefillState] = {}
         self.stats = RunnerStats()
+        self._trace = tracing.tracer()
 
     @property
     def batch_bucket(self) -> int:
@@ -235,13 +237,14 @@ class DecodeRunner:
         history) the device step has usually already finished."""
         if self._pending is None:
             return
-        rows_hist, nxt = self._pending
-        self._pending = None
-        # fslint: disable=FS003(the deferred-sync point: ONE batched d2h pull per step, by design)
-        vals = np.asarray(nxt)
-        self.stats.host_syncs += 1
-        for row, hist in rows_hist:
-            hist.append(int(vals[row]))
+        with self._trace.span("decode.flush"):
+            rows_hist, nxt = self._pending
+            self._pending = None
+            # fslint: disable=FS003(the deferred-sync point: ONE batched d2h pull per step, by design)
+            vals = np.asarray(nxt)
+            self.stats.host_syncs += 1
+            for row, hist in rows_hist:
+                hist.append(int(vals[row]))
 
     # ------------------------------------------------------------------
     # device-state maintenance
@@ -383,24 +386,26 @@ class DecodeRunner:
         pages_bucket = max(self._pages_bucket,
                            next_pow2(max(need_pages, self._min_pages)))
         batch_bucket = max(self._batch_bucket, next_pow2(len(views)))
-        if (pages_bucket != self._pages_bucket
-                or batch_bucket != self._batch_bucket):
-            self._rebuild(views, pages_bucket, batch_bucket)
-        else:
-            self._update_rows(views)
+        with self._trace.span("decode.rows"):
+            if (pages_bucket != self._pages_bucket
+                    or batch_bucket != self._batch_bucket):
+                self._rebuild(views, pages_bucket, batch_bucket)
+            else:
+                self._update_rows(views)
 
-        if self._mesh is None:
-            nxt, pool, self._ctx, self._tok = \
-                paged_decode_step_device(
-                    self._params, pool, self._bt, self._ctx, self._tok,
-                    self._active, self._keys, self._sampling,
-                    cfg=self.mb["cfg"])
-        else:
-            nxt, pool, self._ctx, self._tok = \
-                paged_decode_step_device_sharded(
-                    self._params, pool, self._bt, self._ctx, self._tok,
-                    self._active, self._keys, self._sampling,
-                    cfg=self.mb["cfg"], mesh=self._mesh)
+        with self._trace.span("decode.launch"):
+            if self._mesh is None:
+                nxt, pool, self._ctx, self._tok = \
+                    paged_decode_step_device(
+                        self._params, pool, self._bt, self._ctx, self._tok,
+                        self._active, self._keys, self._sampling,
+                        cfg=self.mb["cfg"])
+            else:
+                nxt, pool, self._ctx, self._tok = \
+                    paged_decode_step_device_sharded(
+                        self._params, pool, self._bt, self._ctx, self._tok,
+                        self._active, self._keys, self._sampling,
+                        cfg=self.mb["cfg"], mesh=self._mesh)
         self._pending = ([(self._rows[v.rid], v.token_history)
                           for v in views], nxt)
         for v in views:
@@ -450,13 +455,14 @@ class DecodeRunner:
         self._row_blocks[row] = ()
         self._row_ctx[row] = 0
         self._free.append(row)
-        self._scatter_rows({row: ((), 0, 0, np.zeros((2,), np.uint32),
-                                  np.zeros((3,), np.float32))})
-        if row in self._active_rows:
-            self._active_rows = self._active_rows - {row}
-            act = np.zeros((self._batch_bucket,), bool)
-            act[list(self._active_rows)] = True
-            self._active = jax.device_put(act, self._device)
+        with self._trace.span("decode.rows", rid):
+            self._scatter_rows({row: ((), 0, 0, np.zeros((2,), np.uint32),
+                                      np.zeros((3,), np.float32))})
+            if row in self._active_rows:
+                self._active_rows = self._active_rows - {row}
+                act = np.zeros((self._batch_bucket,), bool)
+                act[list(self._active_rows)] = True
+                self._active = jax.device_put(act, self._device)
 
     def invariant_report(self, live_rids) -> List[str]:
         """Row-map validation for the engine sanitizer (DESIGN.md §7).
@@ -540,6 +546,7 @@ class DecodeRunner:
         st = self._prefills[rid]
         return len(st.toks) - st.pos
 
+    @tracing.traced("prefill.compute")
     def prefill_chunk_compute(self, rid: int, n_tokens: int) -> Optional[Tuple]:
         """Compute KV for the next ``n_tokens`` of the open prefill: one
         bucketed chunk forward attending the carry buffers (bit-exact
@@ -577,8 +584,9 @@ class DecodeRunner:
         reference to the returned array."""
         if staged is None:
             return pool
-        k, v, blocks = staged
-        return ops.insert_prefill(pool, k, v, blocks, self.bs)
+        with self._trace.span("prefill.insert", rid):
+            k, v, blocks = staged
+            return ops.insert_prefill(pool, k, v, blocks, self.bs)
 
     def _prefill_emit(self, st: _PrefillState) -> None:
         """Emit the response's first token from the final chunk's last
@@ -586,13 +594,14 @@ class DecodeRunner:
         config; bit-exact greedy argmax at temperature 0)."""
         if not st.emit_first or st.emitted:
             return
-        hist = st.view.token_history
-        first_key = self._row_key(st.view.rid, salt=1)
-        smp = jnp.asarray(self._row_sampling(st.view))[None, :]
-        tok = sample_tokens(st.last_logits[None, :], first_key[None, :],
-                            jnp.asarray([len(hist)], jnp.int32), smp)
-        # fslint: disable=FS003(first-token emit must sync: the token gates scheduling and streaming)
-        hist.append(int(tok[0]))
+        with self._trace.span("prefill.first_token", st.view.rid):
+            hist = st.view.token_history
+            first_key = self._row_key(st.view.rid, salt=1)
+            smp = jnp.asarray(self._row_sampling(st.view))[None, :]
+            tok = sample_tokens(st.last_logits[None, :], first_key[None, :],
+                                jnp.asarray([len(hist)], jnp.int32), smp)
+            # fslint: disable=FS003(first-token emit must sync: the token gates scheduling and streaming)
+            hist.append(int(tok[0]))
         st.emitted = True
 
     def prefill_finish(self, rid: int) -> None:
@@ -604,7 +613,8 @@ class DecodeRunner:
             f"prefill_finish with {len(st.toks) - st.pos} tokens pending"
         self._prefill_emit(st)
         self.stats.prefills += 1
-        self._register(st.view)
+        with self._trace.span("decode.rows", rid):
+            self._register(st.view)
 
     def prefill_abort(self, rid: int) -> None:
         """Mid-prefill preemption: drop the carry buffers and the state.
@@ -649,7 +659,8 @@ class DecodeRunner:
         pool = self.prefill_chunk_insert(view.rid, pool, staged)
         self._prefills.pop(view.rid, None)
         self.stats.prefills += 1
-        self._register(view)
+        with self._trace.span("decode.rows", view.rid):
+            self._register(view)
         return pool
 
     def prefill(self, view: DecodeRequestView, pool, *,

@@ -37,7 +37,6 @@ job between steps):
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 from typing import (Callable, Dict, List, Optional, Sequence, Tuple,
                     Union)
@@ -45,6 +44,7 @@ from typing import (Callable, Dict, List, Optional, Sequence, Tuple,
 import numpy as np
 
 from repro.cache.paged import PagedPools, PoolSpec
+from repro.core import tracing
 from repro.core.block_group import (DynamicBlockGroupManager,
                                     OutOfBlocksError)
 from repro.core.decode_runner import DecodeRequestView, DecodeRunner
@@ -76,8 +76,7 @@ class EngineMetrics:
     preemptions: int = 0
     swap_in_count: int = 0
     swap_out_count: int = 0
-    ctx_switch_stall_us: float = 0.0
-    callstack_wall_s: float = 0.0      # REAL wall time of the control plane
+    callstack_wall_s: float = 0.0      # wall time of the ``step`` spans
     aborted: int = 0                   # client cancellations
     dropped: int = 0                   # budget-safeguard drops
     # robustness layer (DESIGN.md §7)
@@ -115,7 +114,6 @@ class EngineMetrics:
             "total_tokens": self.total_tokens,
             "iterations": self.iterations,
             "preemptions": self.preemptions,
-            "ctx_switch_stall_us": self.ctx_switch_stall_us,
             "callstack_wall_s": self.callstack_wall_s,
             "aborted": self.aborted,
             "dropped": self.dropped,
@@ -223,13 +221,17 @@ class ServingEngine:
         # beyond-paper wire compression (int8 KV on the PCIe/DMA link)
         self.block_bytes = self.block_bytes * pol.swap_wire_bytes_per_elem // 2
 
+        # the tracer's index for this engine's spans and marks
+        self._trace = tracing.tracer()
+        self.trace_engine = self._trace.new_engine()
         self.swap = MultithreadingSwapManager(
             hardware, self.pools,
             async_enabled=pol.use_async_swap,
             adaptive=pol.adaptive_async,
             r_info_window=config.r_info_window,
             max_copy_retries=config.swap_max_retries,
-            retry_backoff_us=config.swap_retry_backoff_us)
+            retry_backoff_us=config.swap_retry_backoff_us,
+            trace_engine=self.trace_engine)
         self.iter_cost = IterationCostModel(
             hardware, model_params=model_params,
             kv_bytes_per_token=kv_tok)
@@ -852,6 +854,9 @@ class ServingEngine:
             out.ttft_us = req.ttfts_us[-1]
 
     def _event(self, rid: int, kind: str, **data) -> None:
+        """One request event: ``t_us`` on the engine's clock, and a
+        tracer mark of the same kind on the host's wall clock."""
+        self._trace.mark(kind, rid, engine=self.trace_engine, **data)
         ev = RequestEvent(t_us=self.clock.now_us, handle=rid, kind=kind,
                           data=data)
         if self._event_sink is not None:
@@ -961,6 +966,7 @@ class ServingEngine:
     # swap operations
     # ------------------------------------------------------------------
 
+    @tracing.traced("swap.out")
     def _swap_out(self, rid: int, keep_copy: bool,
                   last_slot_written: bool = False) -> None:
         """Preempt: move KV to CPU.  With reuse, only the increment beyond
@@ -1016,6 +1022,7 @@ class ServingEngine:
         self.gpu_mgr.release_request(rid)
         self.metrics.preemptions += 1
 
+    @tracing.traced("swap.in")
     def _swap_in(self, rid: int) -> bool:
         """Bring a swapped request's KV back to GPU.  Returns True if the
         request is immediately RUNNING (sync), False if in flight."""
@@ -1095,6 +1102,7 @@ class ServingEngine:
         self.sched.move(rid, ReqState.WAITING)
         self._event(rid, "preempt", to="waiting", swap_failure="in")
 
+    @tracing.traced("swap.dispatch")
     def _dispatch_swap(self, rid: int, direction: str,
                        gpu_runs: List[Tuple[int, int]], cpu_ids: List[int],
                        asynchronous: bool) -> None:
@@ -1612,7 +1620,12 @@ class ServingEngine:
         (arrival, wake-up); an idle engine advances its clock no further
         than that, so open-world drivers control time without the engine
         polling."""
-        t_wall0 = time.perf_counter()
+        with self._trace.span("step", engine=self.trace_engine) as sp:
+            outs = self._step(until_us)
+        self.metrics.callstack_wall_s += sp.t1 - sp.t0
+        return outs
+
+    def _step(self, until_us: Optional[float]) -> List[RequestOutput]:
         m = self.metrics
         bs = self.config.block_size
         prefills_before = m.prefills
@@ -1664,43 +1677,47 @@ class ServingEngine:
         # Step 3: priority update -> rebalance
         updated = self.sched.step_trace()
         if updated:
-            desired = self.sched.desired_running(
-                self._budget_tokens(), bs,
-                batch_bucket=(self.runner.batch_bucket
-                              if self.runner is not None else 0))
-            to_preempt, to_swap_in, to_admit = \
-                self.sched.classify_rebalance(desired)
-            for rid in to_preempt:
-                self._contained(rid, self._preempt, rid)
-            for rid in to_swap_in:
-                self._contained(rid, self._swap_in, rid)
-            for rid in to_admit:
-                self._contained(rid, self._admit, rid)
+            with self._trace.span("step.rebalance"):
+                desired = self.sched.desired_running(
+                    self._budget_tokens(), bs,
+                    batch_bucket=(self.runner.batch_bucket
+                                  if self.runner is not None else 0))
+                to_preempt, to_swap_in, to_admit = \
+                    self.sched.classify_rebalance(desired)
+                for rid in to_preempt:
+                    self._contained(rid, self._preempt, rid)
+                for rid in to_swap_in:
+                    self._contained(rid, self._swap_in, rid)
+                for rid in to_admit:
+                    self._contained(rid, self._admit, rid)
 
         # Step 4: opportunistic admission (space permitting), capped at
         # the batch-bucket-aware target instead of max_running outright
-        for rid in sorted(list(self.sched.waiting),
-                          key=self.sched.priority, reverse=True):
-            free_tok = self.gpu_mgr.free_blocks() * bs
-            req = self._req(rid)
-            # the pinned shared prefix is already resident: only the
-            # private tail needs free space
-            need = (req.prefix_tokens + req.current_turn().prompt_tokens
-                    + bs - self._shared_tokens(rid))
-            if need > free_tok \
-                    or len(self.sched.running) + len(self.sched.swapping_in) \
-                    >= self._admission_target():
-                break
-            self._contained(rid, self._admit, rid)
-        for rid in list(self.sched.swapped):
-            if len(self.sched.running) + len(self.sched.swapping_in) \
-                    >= self._admission_target():
-                break
-            free_tok = self.gpu_mgr.free_blocks() * bs
-            if (self._req(rid).context_tokens + bs
-                    - self._shared_tokens(rid)) > free_tok:
-                break
-            self._contained(rid, self._swap_in, rid)
+        if self.sched.waiting or self.sched.swapped:
+            with self._trace.span("step.admit"):
+                for rid in sorted(list(self.sched.waiting),
+                                  key=self.sched.priority, reverse=True):
+                    free_tok = self.gpu_mgr.free_blocks() * bs
+                    req = self._req(rid)
+                    # the pinned shared prefix is already resident: only the
+                    # private tail needs free space
+                    need = (req.prefix_tokens
+                            + req.current_turn().prompt_tokens
+                            + bs - self._shared_tokens(rid))
+                    busy = (len(self.sched.running)
+                            + len(self.sched.swapping_in))
+                    if need > free_tok or busy >= self._admission_target():
+                        break
+                    self._contained(rid, self._admit, rid)
+                for rid in list(self.sched.swapped):
+                    if len(self.sched.running) + len(self.sched.swapping_in) \
+                            >= self._admission_target():
+                        break
+                    free_tok = self.gpu_mgr.free_blocks() * bs
+                    if (self._req(rid).context_tokens + bs
+                            - self._shared_tokens(rid)) > free_tok:
+                        break
+                    self._contained(rid, self._swap_in, rid)
 
         # Step 5: decode one token for the running batch.  Requests with
         # an in-flight chunked prefill advance their prefill instead of
@@ -1744,12 +1761,13 @@ class ServingEngine:
             # entirely — decoding it anyway would advance
             # ``context_tokens`` past its block table (desync).
             skipped: set = set()
-            for rid in list(rids):
-                if rid in skipped or rid not in self.sched.running:
-                    continue       # preempted as a victim earlier this loop
-                if not self._contained(rid, self._allocate_token_slot,
-                                       rid, skipped):
-                    skipped.add(rid)           # retry next iteration
+            with self._trace.span("step.alloc"):
+                for rid in list(rids):
+                    if rid in skipped or rid not in self.sched.running:
+                        continue   # preempted as a victim earlier this loop
+                    if not self._contained(rid, self._allocate_token_slot,
+                                           rid, skipped):
+                        skipped.add(rid)       # retry next iteration
             decode_rids = [r for r in rids if r not in skipped
                            and r in self.sched.running]
             if decode_rids and self.pools is not None:
@@ -1789,7 +1807,6 @@ class ServingEngine:
 
         m.iterations += 1
         m.total_time_us = self.clock.now_us
-        m.ctx_switch_stall_us = self.swap.total_stall_us
 
         # run the recovery ladder again over failures surfaced DURING
         # this step (inline sim copies, fast workers): a terminally
@@ -1813,8 +1830,8 @@ class ServingEngine:
             check_engine_invariants(self)
             m.invariant_checks += 1
 
-        m.callstack_wall_s += time.perf_counter() - t_wall0
-        return self._collect_outputs()
+        with self._trace.span("step.collect"):
+            return self._collect_outputs()
 
     def _process_failed_swaps(self) -> None:
         """Recovery ladder over terminally failed copies surfaced since

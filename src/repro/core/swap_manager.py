@@ -23,6 +23,7 @@ from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Set, Tuple
 
 from repro.cache.paged import PagedPools
+from repro.core import tracing
 from repro.io.cost_model import HardwareSpec, dispatch_time_us, exec_time_us
 
 
@@ -81,7 +82,10 @@ class MultithreadingSwapManager:
                  sync_point_us: float = 5.0, r_info_window: int = 64,
                  sync_stall_frac: float = 0.04,
                  max_copy_retries: int = 2,
-                 retry_backoff_us: float = 200.0):
+                 retry_backoff_us: float = 200.0,
+                 trace_engine: Optional[int] = None):
+        """``trace_engine``: the engine index the tracer files this
+        manager's spans under (worker threads have no engine span open)."""
         self.hw = hw
         self.pools = pools
         # Mesh sharding (DESIGN.md §9): under a model-parallel mesh the
@@ -102,7 +106,9 @@ class MultithreadingSwapManager:
         self.sync_stall_frac = sync_stall_frac
         self._executor = ThreadPoolExecutor(max_workers=n_threads) \
             if pools is not None and pools.with_data else None
-        self._pool_lock = threading.Lock()
+        self._pool_lock = tracing.TracedLock("pool_lock")
+        self._trace = tracing.tracer()
+        self.trace_engine = trace_engine
         # swap-stream timeline (I/O resource occupancy)
         self.stream_free_at = 0.0
         self._dispatches_since_sync = 0
@@ -124,9 +130,7 @@ class MultithreadingSwapManager:
         self.ops_by_dir = {"in": 0, "out": 0}
         self.blocks_by_dir = {"in": 0, "out": 0}
         self.total_stall_us = 0.0          # main-thread (GPU-idle) stall
-        self.total_io_us = 0.0             # swap-stream busy time
         self.n_conflicts = 0
-        self.n_syncs = 0
         self.callstack_overhead_us = 0.0   # fine-grained sync points etc.
         # failure containment (DESIGN.md §7): copy errors never escape a
         # worker — a copy is retried with backoff (charged to the task's
@@ -201,7 +205,6 @@ class MultithreadingSwapManager:
         duration = disp + ex + sync_cost
         done_at = start + duration
         self.stream_free_at = done_at
-        self.total_io_us += duration
         done_at += extra_latency_us
 
         if asynchronous:
@@ -279,28 +282,30 @@ class MultithreadingSwapManager:
                 pass                # dep failures are handled by THEIR task
         if task.copy_fn is None:
             return
-        attempt = 0
-        while True:
-            try:
-                with self._pool_lock:
-                    task.copy_fn()
-                return
-            except Exception as e:
-                attempt += 1
-                task.retries = attempt
-                with self._fail_lock:
-                    self.n_retries += 1
-                    self.retry_log.append({
-                        "rid": task.req_id, "direction": task.direction,
-                        "attempt": attempt,
-                        "error": f"{type(e).__name__}: {e}"})
-                if attempt > self.max_copy_retries:
-                    task.failed = f"{type(e).__name__}: {e}"
-                    with self._fail_lock:
-                        self.n_copy_failures += 1
-                        self.failed_tasks.append(task)
+        with self._trace.span("swap.copy", task.req_id,
+                              engine=self.trace_engine):
+            attempt = 0
+            while True:
+                try:
+                    with self._pool_lock:
+                        task.copy_fn()
                     return
-                task.done_at += self.retry_backoff_us * attempt
+                except Exception as e:
+                    attempt += 1
+                    task.retries = attempt
+                    with self._fail_lock:
+                        self.n_retries += 1
+                        self.retry_log.append({
+                            "rid": task.req_id, "direction": task.direction,
+                            "attempt": attempt,
+                            "error": f"{type(e).__name__}: {e}"})
+                    if attempt > self.max_copy_retries:
+                        task.failed = f"{type(e).__name__}: {e}"
+                        with self._fail_lock:
+                            self.n_copy_failures += 1
+                            self.failed_tasks.append(task)
+                        return
+                    task.done_at += self.retry_backoff_us * attempt
 
     def data_deps(self, cpu_blocks: Sequence[int]) -> List[Future]:
         """Data-plane futures a new copy touching ``cpu_blocks`` must
@@ -344,19 +349,19 @@ class MultithreadingSwapManager:
         tasks = self.ongoing_swap_in if tasks is None else tasks
         if not tasks:
             return
-        target = max(t.done_at for t in tasks)
-        stall = max(0.0, target - clock.now_us)
-        self.total_stall_us += stall
-        clock.advance_to(target)
-        for t in tasks:
-            if t.future is not None:
-                t.future.result()
-        done_ids = {id(t) for t in tasks}
-        self.ongoing_swap_in = [t for t in self.ongoing_swap_in
-                                if id(t) not in done_ids]
-        self.ongoing_swap_out = [t for t in self.ongoing_swap_out
-                                 if id(t) not in done_ids]
-        self.n_syncs += 1
+        with self._trace.span("swap.sync", engine=self.trace_engine):
+            target = max(t.done_at for t in tasks)
+            stall = max(0.0, target - clock.now_us)
+            self.total_stall_us += stall
+            clock.advance_to(target)
+            for t in tasks:
+                if t.future is not None:
+                    t.future.result()
+            done_ids = {id(t) for t in tasks}
+            self.ongoing_swap_in = [t for t in self.ongoing_swap_in
+                                    if id(t) not in done_ids]
+            self.ongoing_swap_out = [t for t in self.ongoing_swap_out
+                                     if id(t) not in done_ids]
 
     def retire_request(self, rid: int) -> int:
         """Abort support: drop an aborted request's in-flight swap-IN
@@ -469,7 +474,8 @@ class MultithreadingSwapManager:
         conflicts = self.detect_conflicts(gpu_blocks)
         if conflicts:
             self.n_conflicts += len(conflicts)
-            self.synchronize(clock, conflicts)
+            with self._trace.span("swap.conflict", engine=self.trace_engine):
+                self.synchronize(clock, conflicts)
         return len(conflicts)
 
     # ------------------------------------------------------------------
@@ -550,9 +556,7 @@ class MultithreadingSwapManager:
             "blocks_in": self.blocks_by_dir["in"],
             "blocks_out": self.blocks_by_dir["out"],
             "total_stall_us": self.total_stall_us,
-            "total_io_us": self.total_io_us,
             "n_conflicts": self.n_conflicts,
-            "n_syncs": self.n_syncs,
             "ongoing": len(self.ongoing_swap_in),
             "callstack_overhead_us": self.callstack_overhead_us,
             "copy_retries": self.n_retries,

@@ -112,19 +112,21 @@ def _gather_swap_body(pool, src_starts, dst_starts, lens, *,
     axis survives as the shard axis under the mesh layout — each shard
     copies its local heads."""
     L, K, nb, bs, H, D = pool.shape
-    slab0 = jnp.zeros((L * K, n_slab, bs, H, D), pool.dtype)
-    return _bc.copy_runs(pool.reshape(L * K, nb, bs, H, D), slab0,
-                         src_starts, dst_starts, lens,
-                         run_blocks=run_blocks, interpret=interpret)
+    with jax.named_scope("swap_gather"):
+        slab0 = jnp.zeros((L * K, n_slab, bs, H, D), pool.dtype)
+        return _bc.copy_runs(pool.reshape(L * K, nb, bs, H, D), slab0,
+                             src_starts, dst_starts, lens,
+                             run_blocks=run_blocks, interpret=interpret)
 
 
 def _scatter_swap_body(pool, slab, src_starts, dst_starts, lens, *,
                        run_blocks: int, interpret: bool):
     L, K, nb, bs, H, D = pool.shape
-    p5 = _bc.copy_runs(slab, pool.reshape(L * K, nb, bs, H, D),
-                       src_starts, dst_starts, lens,
-                       run_blocks=run_blocks, interpret=interpret)
-    return p5.reshape(pool.shape)
+    with jax.named_scope("swap_scatter"):
+        p5 = _bc.copy_runs(slab, pool.reshape(L * K, nb, bs, H, D),
+                           src_starts, dst_starts, lens,
+                           run_blocks=run_blocks, interpret=interpret)
+        return p5.reshape(pool.shape)
 
 
 _gather_swap = jax.jit(_gather_swap_body,
@@ -240,8 +242,9 @@ def swap_scatter_cache_size() -> int:
 def _insert_prefill(pool, k, v, blocks, *, block_size: int):
     L, T, H, D = k.shape
     P = T // block_size
-    kv = jnp.stack([k, v], axis=1).reshape(L, 2, P, block_size, H, D)
-    return pool.at[:, :, blocks].set(kv.astype(pool.dtype))
+    with jax.named_scope("prefill_insert"):
+        kv = jnp.stack([k, v], axis=1).reshape(L, 2, P, block_size, H, D)
+        return pool.at[:, :, blocks].set(kv.astype(pool.dtype))
 
 
 def insert_prefill(pool, k, v, blocks, block_size: int):
@@ -287,9 +290,11 @@ def _grow_carry(carry, *, n_new: int):
     """Copy a (L, S_old, H, D) prefill carry into a longer zeroed buffer
     (pow2 token bucket).  Not donated: the output shape differs, so XLA
     could never reuse the old buffer anyway (it would only warn)."""
-    return jax.lax.dynamic_update_slice(
-        jnp.zeros(carry.shape[:1] + (n_new,) + carry.shape[2:], carry.dtype),
-        carry, (0, 0, 0, 0))
+    with jax.named_scope("carry_grow"):
+        return jax.lax.dynamic_update_slice(
+            jnp.zeros(carry.shape[:1] + (n_new,) + carry.shape[2:],
+                      carry.dtype),
+            carry, (0, 0, 0, 0))
 
 
 @functools.partial(jax.jit, static_argnames=("n",))
@@ -362,9 +367,10 @@ def _seed_carry(pool, blocks):
     buffers.  Specializes on (pool shape, P_pad) — pow2-padded pages,
     O(log) variants."""
     L, K, _, bs, H, D = pool.shape
-    kv = pool[:, :, blocks]                     # (L, 2, P_pad, bs, H, D)
-    kv = kv.reshape(L, K, blocks.shape[0] * bs, H, D)
-    return kv[:, 0], kv[:, 1]
+    with jax.named_scope("carry_seed"):
+        kv = pool[:, :, blocks]                 # (L, 2, P_pad, bs, H, D)
+        kv = kv.reshape(L, K, blocks.shape[0] * bs, H, D)
+        return kv[:, 0], kv[:, 1]
 
 
 def seed_prefill_carry(pool, block_ids: Sequence[int], start_tokens: int,

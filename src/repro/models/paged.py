@@ -88,9 +88,10 @@ def _decode_hidden(params, pool, block_tables, context_lens, tokens,
         off = context_lens % bs
         pool_l = pool_l.at[0, blk, off].set(k[:, 0].astype(pool_l.dtype))
         pool_l = pool_l.at[1, blk, off].set(v[:, 0].astype(pool_l.dtype))
-        a = ops.paged_attention(q[:, 0], pool_l[0], pool_l[1],
-                                block_tables, context_lens + 1, scale,
-                                pages_per_compute_block=ppcb)
+        with jax.named_scope("paged_attn"):
+            a = ops.paged_attention(q[:, 0], pool_l[0], pool_l[1],
+                                    block_tables, context_lens + 1, scale,
+                                    pages_per_compute_block=ppcb)
         if axis_name is not None:
             # concat this shard's head outputs with the others' (device
             # order == head order, bit-exact) ahead of the replicated wo
@@ -103,7 +104,8 @@ def _decode_hidden(params, pool, block_tables, context_lens, tokens,
             f = L.swiglu(lp["ffn"], h)
         return x + f, pool_l
 
-    x, new_pool = jax.lax.scan(body, x, (params["layers"], pool))
+    with jax.named_scope("decode_layers"):
+        x, new_pool = jax.lax.scan(body, x, (params["layers"], pool))
     x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
     return x[:, 0], new_pool
 
@@ -145,6 +147,11 @@ def sample_tokens(logits, keys, ctx, sampling):
     top_p] (top_k column 0 = disabled; stored as float, exact for any
     realistic k).  Returns tokens (B,) i32.
     """
+    with jax.named_scope("sampling"):
+        return _sample_tokens(logits, keys, ctx, sampling)
+
+
+def _sample_tokens(logits, keys, ctx, sampling):
     B, V = logits.shape
     logits = logits.astype(jnp.float32)
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -370,8 +377,9 @@ def _prefill_chunk_core(params, tokens, k_carry, v_carry, prefix_len,
             f = L.swiglu(lp["ffn"], h)
         return x + f, (kc, vc)
 
-    x, (k_carry, v_carry) = jax.lax.scan(
-        body, x, (params["layers"], k_carry, v_carry))
+    with jax.named_scope("prefill_forward"):
+        x, (k_carry, v_carry) = jax.lax.scan(
+            body, x, (params["layers"], k_carry, v_carry))
     x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
     # unembed ONLY the last real position (row-wise matmul is bitwise
     # independent of the batch of rows, so this equals slicing the full
