@@ -29,7 +29,8 @@ def main(emit=print):
     vp = jax.random.normal(ks[2], (nb, bs, Hkv, D), jnp.float32)
     bt = jax.random.permutation(ks[3], nb)[:B * npages].reshape(B, npages)
     ctx = jnp.full((B,), npages * bs, jnp.int32)
-    t = _time(lambda: paged_attention(q, kp, vp, bt.astype(jnp.int32),
+    t = _time(lambda: paged_attention(q, jnp.stack([kp, vp])[None], 0,
+                                      bt.astype(jnp.int32),
                                       ctx, D ** -0.5))
     emit(csv_line("kernel_paged_attention_interp", t,
                   f"B{B}xH{Hq}x{npages}pages"))

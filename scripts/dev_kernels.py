@@ -11,7 +11,7 @@ vp = jax.random.normal(ks[2], (nb, bs, Hkv, D), jnp.float32)
 bt = jax.random.permutation(ks[3], nb)[:B * npages].reshape(B, npages).astype(jnp.int32)
 ctx = jnp.array([5, 33, 64], jnp.int32)
 ref = paged_attention_ref(q, jnp.stack([kp, vp]), bt, ctx, 0.125)
-out = paged_attention(q, kp, vp, bt, ctx, 0.125)
+out = paged_attention(q, jnp.stack([kp, vp])[None], 0, bt, ctx, 0.125)
 print("paged_attention maxerr", float(jnp.max(jnp.abs(ref - out))))
 
 # block copy

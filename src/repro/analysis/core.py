@@ -64,7 +64,7 @@ class Config:
     # contains a call to one of these become derived bucketing sources
     # (e.g. kernels/ops.py::_pad_runs).
     bucketing_helpers: Tuple[str, ...] = (
-        "next_pow2", "_next_pow2", "slab_bucket_blocks", "page_tile",
+        "next_pow2", "_next_pow2", "slab_bucket_blocks",
         "_grow_to", "pow2_bucket",
     )
 

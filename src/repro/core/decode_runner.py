@@ -88,6 +88,16 @@ def next_pow2(n: int) -> int:
     return 1 if n <= 1 else 1 << (n - 1).bit_length()
 
 
+@jax.jit
+def _draw_first(logits, key, pos, sampling):
+    """A prefill's first-token draw as one compiled program: called
+    eagerly, ``sample_tokens`` traces and lowers its ``lax.cond`` again
+    on every call.  logits (V,); key (2,) u32; pos () i32; sampling (3,)
+    f32 -> () i32."""
+    return sample_tokens(logits[None, :], key[None, :], pos[None],
+                         sampling[None, :])[0]
+
+
 @dataclass
 class DecodeRequestView:
     """What the runner needs to know about one decoding request."""
@@ -394,6 +404,14 @@ class DecodeRunner:
                 self._update_rows(views)
 
         with self._trace.span("decode.launch"):
+            # the attention kernel reads each row's live pages (its block
+            # count, or one fewer where its next page is allocated early)
+            # and a padding row's one trash page, of batch x pages
+            self._trace.mark(
+                "paged_attn.pages",
+                live=(sum(len(v.block_ids) for v in views)
+                      + batch_bucket - len(views)),
+                bucket=batch_bucket * pages_bucket)
             if self._mesh is None:
                 nxt, pool, self._ctx, self._tok = \
                     paged_decode_step_device(
@@ -596,12 +614,12 @@ class DecodeRunner:
             return
         with self._trace.span("prefill.first_token", st.view.rid):
             hist = st.view.token_history
-            first_key = self._row_key(st.view.rid, salt=1)
-            smp = jnp.asarray(self._row_sampling(st.view))[None, :]
-            tok = sample_tokens(st.last_logits[None, :], first_key[None, :],
-                                jnp.asarray([len(hist)], jnp.int32), smp)
+            tok = _draw_first(st.last_logits,
+                              self._row_key(st.view.rid, salt=1),
+                              np.int32(len(hist)),
+                              self._row_sampling(st.view))
             # fslint: disable=FS003(first-token emit must sync: the token gates scheduling and streaming)
-            hist.append(int(tok[0]))
+            hist.append(int(tok))
         st.emitted = True
 
     def prefill_finish(self, rid: int) -> None:

@@ -37,11 +37,10 @@ def interpret_mode() -> bool:
                            "mode)") from None
 
 
-def paged_attention(q, k_pool, v_pool, block_tables, context_lens, scale,
-                    pages_per_compute_block: int = 1):
-    return _pa.paged_attention(q, k_pool, v_pool, block_tables, context_lens,
-                               scale,
-                               pages_per_compute_block=pages_per_compute_block,
+def paged_attention(q, kv_pool, layer, block_tables, context_lens, scale,
+                    pages_per_tile=None):
+    return _pa.paged_attention(q, kv_pool, layer, block_tables, context_lens,
+                               scale, pages_per_tile=pages_per_tile,
                                interpret=interpret_mode())
 
 

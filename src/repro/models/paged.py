@@ -42,16 +42,6 @@ def supports_paged(cfg: ModelConfig) -> bool:
             and not cfg.encoder_decoder)
 
 
-def page_tile(n_pages: int) -> int:
-    """Pages streamed per attention grid step: the largest of {4, 2, 1}
-    dividing n_pages (bucketed page counts are powers of two, so steady
-    state always gets the 4-page tile)."""
-    for p in (4, 2):
-        if n_pages % p == 0 and n_pages >= p:
-            return p
-    return 1
-
-
 def _decode_hidden(params, pool, block_tables, context_lens, tokens,
                    cfg: ModelConfig, axis_name=None):
     """Shared decode body up to the final norm: one token per row
@@ -70,28 +60,26 @@ def _decode_hidden(params, pool, block_tables, context_lens, tokens,
     assert supports_paged(cfg), cfg.name
     B = tokens.shape[0]
     bs = pool.shape[3]
-    n_pages = block_tables.shape[1]
     x = L.embed(params["embed"], tokens[:, None])          # (B, 1, d)
     positions = context_lens[:, None]                      # rope positions
     scale = 1.0 / math.sqrt(cfg.resolved_head_dim)
     use_moe = cfg.moe is not None
     barange = jnp.arange(B)
-    ppcb = page_tile(n_pages)
 
-    def body(x, xs):
-        lp, pool_l = xs                                    # pool_l: (2,nb,bs,H,D)
+    def body(carry, xs):
+        x, pool = carry
+        lp, layer = xs
         h = L.rmsnorm(lp["ln1"], x, cfg.norm_eps)
         q, k, v = attn._project_qkv(lp["attn"], h, cfg)
         q = L.apply_rope(q, positions, cfg.rope_theta)
         k = L.apply_rope(k, positions, cfg.rope_theta)
         blk = block_tables[barange, context_lens // bs]
         off = context_lens % bs
-        pool_l = pool_l.at[0, blk, off].set(k[:, 0].astype(pool_l.dtype))
-        pool_l = pool_l.at[1, blk, off].set(v[:, 0].astype(pool_l.dtype))
+        pool = pool.at[layer, 0, blk, off].set(k[:, 0].astype(pool.dtype))
+        pool = pool.at[layer, 1, blk, off].set(v[:, 0].astype(pool.dtype))
         with jax.named_scope("paged_attn"):
-            a = ops.paged_attention(q[:, 0], pool_l[0], pool_l[1],
-                                    block_tables, context_lens + 1, scale,
-                                    pages_per_compute_block=ppcb)
+            a = ops.paged_attention(q[:, 0], pool, layer,
+                                    block_tables, context_lens + 1, scale)
         if axis_name is not None:
             # concat this shard's head outputs with the others' (device
             # order == head order, bit-exact) ahead of the replicated wo
@@ -102,10 +90,14 @@ def _decode_hidden(params, pool, block_tables, context_lens, tokens,
             f, _ = moe_mod.moe_forward(lp["ffn"], h, cfg)
         else:
             f = L.swiglu(lp["ffn"], h)
-        return x + f, pool_l
+        return (x + f, pool), None
 
+    # the pool rides the carry whole, written in place and read by the
+    # kernel at ``layer``: a layer sliced out as a scan input is staged
+    # through VMEM and written back, a copy each way per layer
     with jax.named_scope("decode_layers"):
-        x, new_pool = jax.lax.scan(body, x, (params["layers"], pool))
+        (x, new_pool), _ = jax.lax.scan(
+            body, (x, pool), (params["layers"], jnp.arange(pool.shape[0])))
     x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
     return x[:, 0], new_pool
 

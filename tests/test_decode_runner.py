@@ -162,15 +162,17 @@ def test_swap_round_trip_bit_exact_and_kernel_parity(model):
     got = np.asarray(pools.gpu, np.float32)
     np.testing.assert_array_equal(got[:, :, used], snap[:, :, used])
 
-    # kernel vs reference on the round-tripped pool, ppcb > 1, ragged tile
-    kp, vp = pools.gpu[0, 0], pools.gpu[0, 1]
+    # kernel vs reference on the round-tripped pool's last layer, 2-page
+    # tiles, ragged last tile
+    layer = cfg.n_layers - 1
+    kp, vp = pools.gpu[layer, 0], pools.gpu[layer, 1]
     q = jax.random.normal(key, (2, cfg.n_heads, cfg.resolved_head_dim),
                           jnp.bfloat16)
     bt = jnp.asarray([[1, 3, 4], [6, 4, 1]], jnp.int32)
     ctx = jnp.asarray([3 * BS, 2 * BS - 1], jnp.int32)
     scale = cfg.resolved_head_dim ** -0.5
-    out = paged_attention(q, kp, vp, bt, ctx, scale,
-                          pages_per_compute_block=2)
+    out = paged_attention(q, pools.gpu, layer, bt, ctx, scale,
+                          pages_per_tile=2)
     ref = paged_attention_ref(q, jnp.stack([kp, vp]), bt, ctx, scale)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32),
@@ -372,6 +374,38 @@ def test_runner_prefill_matches_host_write_path(model):
         return hist
 
     assert runner_path() == legacy()
+
+
+def test_first_token_draw_builds_no_program_after_the_first(model):
+    """The prefill's first-token draw is one compiled program: once it
+    is built, later draws of other tokens trace, lower and build
+    nothing (called eagerly, the sampler's ``lax.cond`` is lowered again
+    on every draw)."""
+    from repro.core import tracing
+    cfg, params = model
+    nb = 16
+    pool = _mk_pool(cfg, nb)
+    runner = DecodeRunner({"cfg": cfg, "params": params},
+                          block_size=BS, trash_block=nb - 1)
+    tr = tracing.tracer()
+    built, firsts = [], []
+    for rid in range(3):
+        hist = [int(x) for x in np.random.RandomState(10 + rid).randint(
+            1, cfg.vocab_size, 9)]
+        view = DecodeRequestView(rid, [3 * rid, 3 * rid + 1, 3 * rid + 2],
+                                 hist)
+        total = runner.prefill_begin(view, emit_first=True)
+        staged = runner.prefill_chunk_compute(rid, total)
+        runner.prefill_emit_first(rid)
+        draw = [s for s in tr.spans()
+                if s.name == "prefill.first_token" and s.rid == rid][-1]
+        built.append(sorted(s.name for s in tr.spans()
+                            if s.parent == draw.id))
+        firsts.append(hist[-1])
+        pool = runner.prefill_insert(view, pool, staged)
+        assert len(hist) == 10
+    assert built[1:] == [[], []]
+    assert len(set(firsts)) == 3          # three different tokens drawn
 
 
 def test_flush_is_idempotent_and_deferred(model):

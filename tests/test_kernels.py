@@ -43,20 +43,21 @@ def test_paged_attention_sweep(B, Hq, Hkv, D, bs, npages, dtype):
     lens = np.linspace(1, npages * bs, B).astype(np.int32)
     ctx = jnp.asarray(lens)
     scale = D ** -0.5
-    out = paged_attention(q, kp, vp, bt, ctx, scale)
-    ref = paged_attention_ref(q, jnp.stack([kp, vp]), bt, ctx, scale)
+    kv = jnp.stack([kp, vp])
+    out = paged_attention(q, kv[None], 0, bt, ctx, scale)
+    ref = paged_attention_ref(q, kv, bt, ctx, scale)
     tol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32),
                                atol=tol, rtol=tol)
 
 
-@pytest.mark.parametrize("ppcb", [1, 2, 4])
+@pytest.mark.parametrize("ppt", [1, 2, 4])
 @pytest.mark.parametrize("npages", [3, 4, 5, 8])
 @pytest.mark.parametrize("Hq,Hkv,D", [(8, 2, 64), (12, 2, 128)])
-def test_paged_attention_multipage_tiles(ppcb, npages, Hq, Hkv, D):
-    """pages_per_compute_block > 1 streams several KV pages per grid step;
-    npages not divisible by ppcb exercises the ragged final tile."""
+def test_paged_attention_multipage_tiles(ppt, npages, Hq, Hkv, D):
+    """Tiles of ``ppt`` pages; npages not divisible by ppt exercises the
+    ragged final tile."""
     B, bs = 2, 16
     nb = npages * B + 3
     ks = jax.random.split(jax.random.PRNGKey(42), 4)
@@ -67,9 +68,64 @@ def test_paged_attention_multipage_tiles(ppcb, npages, Hq, Hkv, D):
         .reshape(B, npages).astype(jnp.int32)
     ctx = jnp.asarray(np.linspace(1, npages * bs, B).astype(np.int32))
     scale = D ** -0.5
-    out = paged_attention(q, kp, vp, bt, ctx, scale,
-                          pages_per_compute_block=ppcb)
+    kv = jnp.stack([kp, vp])
+    out = paged_attention(q, kv[None], 0, bt, ctx, scale, pages_per_tile=ppt)
+    ref = paged_attention_ref(q, kv, bt, ctx, scale)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32),
+                               atol=1e-5, rtol=1e-5)
+
+
+def _live_rows_case(Hkv, seed=7):
+    """Rows as the decode step sends them: two padding rows (context 1,
+    every page the trash page), a ragged last tile, a row that fills the
+    bucket.  Returns the finite pools, a copy of them whose pages past
+    each row's last live page hold NaN, and the call's arguments."""
+    B, Hq, D, bs, npages = 4, 2 * Hkv, 128, 16, 8
+    trash = 0
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    nb = 1 + 2 * npages
+    q = jax.random.normal(ks[0], (B, Hq, D))
+    kp = jax.random.normal(ks[1], (nb, bs, Hkv, D))
+    vp = jax.random.normal(ks[2], (nb, bs, Hkv, D))
+    pages = np.asarray(jax.random.permutation(ks[3], np.arange(1, nb)))
+    ctx = np.asarray([1, 2 * bs + 5, npages * bs, 1], np.int32)
+    bt = np.full((B, npages), trash, np.int32)
+    bt[1, :] = pages[:npages]
+    bt[2, :] = pages[npages:2 * npages]
+    dead = np.zeros((nb,), bool)
+    for b in (1, 2):
+        live = -(-int(ctx[b]) // bs)
+        dead[bt[b, live:]] = True
+    nan = jnp.where(jnp.asarray(dead)[:, None, None, None], jnp.nan, 1.0)
+    return (kp, vp), (kp * nan, vp * nan), (q, jnp.asarray(bt),
+                                           jnp.asarray(ctx), D ** -0.5)
+
+
+@pytest.mark.parametrize("ppt", [1, 2, 4])
+@pytest.mark.parametrize("Hkv", [1, 2])
+def test_paged_attention_never_reads_dead_pages(Hkv, ppt):
+    """Pages past each row's last live page hold NaN: the output still
+    matches the reference on the finite pool, so no dead page is read."""
+    (kp, vp), (kn, vn), (q, bt, ctx, scale) = _live_rows_case(Hkv)
+    assert bool(jnp.any(jnp.isnan(kn)))
+    # the poisoned pool as layer 1 of two, behind a finite layer 0
+    pool = jnp.stack([jnp.stack([kp, vp]), jnp.stack([kn, vn])])
+    out = paged_attention(q, pool, 1, bt, ctx, scale, pages_per_tile=ppt)
     ref = paged_attention_ref(q, jnp.stack([kp, vp]), bt, ctx, scale)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32),
+                               atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("ppt", [None, 1, 4])
+def test_paged_attention_padding_ragged_and_full_rows(ppt):
+    """Padding rows (context 1 on the trash page), a ragged last tile and
+    a row at the full bucket, at the tile the shapes choose and others."""
+    (kp, vp), _, (q, bt, ctx, scale) = _live_rows_case(2)
+    kv = jnp.stack([kp, vp])
+    out = paged_attention(q, kv[None], 0, bt, ctx, scale, pages_per_tile=ppt)
+    ref = paged_attention_ref(q, kv, bt, ctx, scale)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32),
                                atol=1e-5, rtol=1e-5)
@@ -81,7 +137,7 @@ def test_paged_attention_zero_context_is_finite():
     vp = jnp.ones((4, 16, 2, 64))
     bt = jnp.zeros((2, 2), jnp.int32)
     ctx = jnp.array([0, 5], jnp.int32)
-    out = paged_attention(q, kp, vp, bt, ctx, 0.125)
+    out = paged_attention(q, jnp.stack([kp, vp])[None], 0, bt, ctx, 0.125)
     assert bool(jnp.all(jnp.isfinite(out)))
 
 

@@ -4,7 +4,8 @@ Interpret mode (what every other kernel test runs on the CPU) never
 checks Mosaic's tiling rules or VMEM limits; the TPU compiler installed
 with JAX does, for a chip that is described and not attached.  Shapes
 are Qwen2-1.5B's serving widths: 28 layers, 2 KV heads, head_dim 128,
-12 query heads, block size 16, a bf16 pool and float32 queries.
+12 query heads, block size 16, a bf16 pool and bf16 queries (the dtype
+the decode step passes).
 
 The topology is described inside a module-scoped fixture, never while
 a module is imported: only one process at a time may load the TPU
@@ -49,15 +50,18 @@ def _spec(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-@pytest.mark.parametrize("ppcb", [1, 4])
-def test_paged_attention_compiles_for_v5e(one_chip, ppcb):
-    B, n_pages = 8, 128
+@pytest.mark.parametrize("B,n_pages", [(32, 256), (3, 5)],
+                         ids=["chat-bucket", "ragged"])
+def test_paged_attention_compiles_for_v5e(one_chip, B, n_pages):
+    """The chat cell's bucket (32 rows x 256 pages), and a small bucket
+    whose page count no power of two divides; the tile comes from the
+    shapes."""
     fn = functools.partial(paged_attention, scale=D ** -0.5,
-                           pages_per_compute_block=ppcb, interpret=False)
+                           interpret=False)
     compiled = jax.jit(fn).lower(
-        _spec((B, HQ, D), jnp.float32, one_chip),
-        _spec((NB, BS, HKV, D), jnp.bfloat16, one_chip),
-        _spec((NB, BS, HKV, D), jnp.bfloat16, one_chip),
+        _spec((B, HQ, D), jnp.bfloat16, one_chip),
+        _spec((L, 2, NB, BS, HKV, D), jnp.bfloat16, one_chip),
+        _spec((), jnp.int32, one_chip),
         _spec((B, n_pages), jnp.int32, one_chip),
         _spec((B,), jnp.int32, one_chip)).compile()
     assert "tpu_custom_call" in compiled.as_text()

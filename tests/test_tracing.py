@@ -276,9 +276,9 @@ def test_engine_emits_every_span_and_mark_with_its_rid(tiny_model):
     kinds = {m.name for m in marks}
     assert {"arrive", "continue", "admit", "swap_in", "first_token",
             "preempt", "finish"} <= kinds
-    # each mark comes from the engine's event stream, one for one
-    assert [(m.name, m.rid) for m in marks] == \
-        [(e.kind, e.handle) for e in eng.events]
+    # each request mark comes from the engine's event stream, one for one
+    assert [(m.name, m.rid) for m in marks if m.name != "paged_attn.pages"] \
+        == [(e.kind, e.handle) for e in eng.events]
     # a swap-in mark lies inside the swap.in span of the same request
     for m in marks:
         if m.name == "swap_in":
@@ -304,6 +304,40 @@ def test_engine_emits_every_span_and_mark_with_its_rid(tiny_model):
     assert {"step", "swap_out", "swap_in", "swap_copy", "swap_sync",
             "prefill", "flush_wait", "decode_dispatch",
             "prefill_compute"} <= seen
+
+
+def test_each_decode_launch_marks_live_and_bucket_pages(tiny_model):
+    """``paged_attn.pages``: ``live`` is the rows' block counts plus one
+    trash page per padding row, ``bucket`` the batch bucket times the
+    pages bucket.  The kernel reads each row's ``cdiv(ctx + 1, bs)``
+    pages: the block count, or one fewer where the row's next page is
+    already allocated."""
+    eng = _engine(tiny_model, "fastswitch")
+    runner = eng.runner
+    launches = []
+    decode = runner.decode
+
+    def record(views, pool):
+        out = decode(views, pool)
+        pad = runner.batch_bucket - len(views)
+        # the host mirror of a row's context, counted after the launch,
+        # is the context the kernel attended (the new token included)
+        read = [-(-runner._row_ctx[runner._rows[v.rid]] // runner.bs)
+                for v in views]
+        blocks = [len(v.block_ids) for v in views]
+        assert all(0 <= n - r <= 1 for n, r in zip(blocks, read))
+        launches.append((sum(blocks) + pad,
+                         runner.batch_bucket * runner._pages_bucket))
+        return out
+
+    runner.decode = record
+    _serve_two_sessions(eng)
+    marks = [m for m in tracing.tracer().marks()
+             if m.engine == eng.trace_engine
+             and m.name == "paged_attn.pages"]
+    assert len(marks) == len(launches) == runner.stats.steps > 0
+    assert [(m.data["live"], m.data["bucket"]) for m in marks] == launches
+    assert any(live < bucket for live, bucket in launches)
 
 
 def test_a_recompute_resume_is_marked(tiny_model):
