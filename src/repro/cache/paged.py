@@ -25,7 +25,7 @@ only) so thousand-conversation runs stay fast.
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import List, Optional, Sequence, Tuple
+from typing import Callable, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -151,24 +151,40 @@ class PagedPools:
     # -- staged data plane (the engine's swap path, DESIGN.md §4) ---------
 
     def copy_out_staged(self, gpu_runs: Sequence[Tuple[int, int]],
-                        cpu_blocks: List[int]) -> None:
+                        cpu_blocks: List[int], *, deferred: bool = False
+                        ) -> Optional[Callable[[], None]]:
         """GPU -> CPU via the device staging slab: one grouped gather
         kernel coalesces ``gpu_runs`` [(start, n)] into a contiguous
         slab, then ONE d2h transfer moves the slab; the host side is a
-        single vectorized store of the bf16 bit pattern."""
+        single vectorized store of the bf16 bit pattern.
+
+        ``deferred``: wait only for the gather, the one part that reads
+        the pool, and return the rest (the transfer and the store) for
+        the caller to run, which may do so without the pool lock."""
         if not self.with_data or not gpu_runs:
-            return
+            return None
         slab, total = ops.gather_swap_runs(self.gpu, gpu_runs,
                                            mesh=self.mesh)
         assert total == len(cpu_blocks), (total, len(cpu_blocks))
-        # ONE d2h per shard (the slab prefix; head-sharded under a mesh)
-        sliced = slab[:, :total]
-        host = np.asarray(sliced)
         self.staged_out_calls += 1
-        self.d2h_transfers += len(sliced.sharding.device_set)
-        s = self.spec
-        self.cpu[:, :, np.asarray(cpu_blocks)] = host.view(np.uint16).reshape(
-            s.n_layers, 2, total, s.block_size, s.n_kv_heads, s.head_dim)
+        self.d2h_transfers += len(slab.sharding.device_set)
+
+        def store() -> None:
+            # ONE d2h per shard of the whole pow2 slab (head-sharded under
+            # a mesh), trimmed to ``total`` on the host: a slice on the
+            # device would build a program for every block count new to
+            # the process
+            host = np.asarray(slab)[:, :total]
+            s = self.spec
+            self.cpu[:, :, np.asarray(cpu_blocks)] = \
+                host.view(np.uint16).reshape(s.n_layers, 2, total,
+                                             s.block_size, s.n_kv_heads,
+                                             s.head_dim)
+        if not deferred:
+            store()
+            return None
+        slab.block_until_ready()
+        return store
 
     def copy_in_staged(self, cpu_blocks: List[int],
                        gpu_runs: Sequence[Tuple[int, int]],

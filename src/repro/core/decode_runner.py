@@ -88,6 +88,25 @@ def next_pow2(n: int) -> int:
     return 1 if n <= 1 else 1 << (n - 1).bit_length()
 
 
+# the per-row PRNG key fold as one program, not a chain of eager ops
+_fold_in = jax.jit(jax.random.fold_in)
+
+
+@jax.jit
+def _write_rows(bt, ctx, tok, keys, smp, rows, bt_rows, full, ctx_v, tok_v,
+                key_v, smp_v):
+    """The decode tables with ``rows`` rewritten in one program: each
+    row's block table, and the context, token, key and sampling row of
+    the rows ``full`` marks (the others keep theirs).  A row may repeat
+    with the same values (the pow2 padding), so the write order is
+    moot."""
+    def put(a, v):
+        m = full.reshape((-1,) + (1,) * (v.ndim - 1))
+        return a.at[rows].set(jnp.where(m, v, a[rows]))
+    return (bt.at[rows].set(bt_rows), put(ctx, ctx_v), put(tok, tok_v),
+            put(keys, key_v), put(smp, smp_v))
+
+
 @jax.jit
 def _draw_first(logits, key, pos, sampling):
     """A prefill's first-token draw as one compiled program: called
@@ -225,8 +244,8 @@ class DecodeRunner:
         (seed, rid, position) — reproducible under any preemption order,
         row re-registration or bucket rebuild.  ``salt`` separates the
         prefill first-token draw from the row's decode stream."""
-        k = jax.random.fold_in(self._base_key, rid)
-        return jax.random.fold_in(k, salt) if salt else k
+        k = _fold_in(self._base_key, rid)
+        return _fold_in(k, salt) if salt else k
 
     def _row_sampling(self, view: DecodeRequestView) -> np.ndarray:
         """The (3,) f32 [temperature, top_k, top_p] row for ``view`` —
@@ -291,6 +310,17 @@ class DecodeRunner:
             self._sampling = jax.device_put((bt, ctx, tok, keys, act, smp),
                                             self._device)
         self._active_rows = frozenset(range(len(views)))
+        # build the row write of every pow2 row count for the new tables
+        # now, not inside a serving step: each writes rows [0, n) with
+        # the values they hold
+        n = 1
+        while n <= batch_bucket:
+            self._bt, self._ctx, self._tok, self._keys, self._sampling = \
+                _write_rows(self._bt, self._ctx, self._tok, self._keys,
+                            self._sampling, np.arange(n, dtype=np.int32),
+                            bt[:n], np.ones((n,), bool), ctx[:n], tok[:n],
+                            keys[:n], smp[:n])
+            n *= 2
 
     def _scatter_rows(self, pending: Dict[int, Tuple[Tuple[int, ...],
                                                      Optional[int],
@@ -304,29 +334,28 @@ class DecodeRunner:
         (block-table-only write)."""
         if not pending:
             return
-        pb = self._pages_bucket
-        entries = [(r, ids, c, t, kd, sr)
-                   for r, (ids, c, t, kd, sr) in sorted(pending.items())]
-        rows = jnp.asarray([e[0] for e in entries], jnp.int32)
-        btrows = np.full((len(entries), pb), self.trash, np.int32)
-        for j, (_, ids, _, _, _, _) in enumerate(entries):
+        entries = sorted(pending.items())
+        # pad to a pow2 count by repeating the first entry: one program
+        # per pow2, all built with the tables (``_rebuild``)
+        n = next_pow2(len(entries))
+        entries += [entries[0]] * (n - len(entries))
+        rows = np.asarray([r for r, _ in entries], np.int32)
+        btrows = np.full((n, self._pages_bucket), self.trash, np.int32)
+        full = np.zeros((n,), bool)
+        ctx = np.zeros((n,), np.int32)
+        tok = np.zeros((n,), np.int32)
+        keys = np.zeros((n, 2), np.uint32)
+        smp = np.zeros((n, 3), np.float32)
+        for j, (_, (ids, c, t, kd, sr)) in enumerate(entries):
             btrows[j, :len(ids)] = ids
-        self._bt = self._bt.at[rows].set(jnp.asarray(btrows))
-        full = [(r, c, t, kd, sr)
-                for r, _, c, t, kd, sr in entries if c is not None]
-        if full:
-            frows = jnp.asarray([f[0] for f in full], jnp.int32)
-            self._ctx = self._ctx.at[frows].set(
-                jnp.asarray([f[1] for f in full], jnp.int32))
-            self._tok = self._tok.at[frows].set(
-                jnp.asarray([f[2] for f in full], jnp.int32))
-            self._keys = self._keys.at[frows].set(
-                jnp.asarray(np.stack([np.asarray(f[3], np.uint32)
-                                      for f in full])))
-            self._sampling = self._sampling.at[frows].set(
-                jnp.asarray(np.stack([np.asarray(f[4], np.float32)
-                                      for f in full])))
-        self.stats.rows_updated += len(entries)
+            if c is not None:
+                full[j], ctx[j], tok[j] = True, c, t
+                keys[j], smp[j] = np.asarray(kd, np.uint32), sr
+        self._bt, self._ctx, self._tok, self._keys, self._sampling = \
+            _write_rows(self._bt, self._ctx, self._tok, self._keys,
+                        self._sampling, rows, btrows, full, ctx, tok, keys,
+                        smp)
+        self.stats.rows_updated += len(pending)
 
     def _update_rows(self, views: List[DecodeRequestView]) -> None:
         """Incremental path: scatter in only the rows that changed."""

@@ -1138,7 +1138,8 @@ class ServingEngine:
                     data_runs = trim_runs(runs_c, len(cpu_c))
                     if direction == "out":
                         copy_fn = (lambda r=data_runs, c=cpu_c:
-                                   pools.copy_out_staged(r, c))
+                                   pools.copy_out_staged(r, c,
+                                                         deferred=True))
                     else:
                         copy_fn = (lambda r=data_runs, c=cpu_c:
                                    pools.copy_in_staged(c, r))

@@ -53,7 +53,9 @@ class SwapTask:
     future: Optional[Future] = None
     synchronous: bool = False
     # failure containment (DESIGN.md §7): the copy closure is retained so
-    # retries and the watchdog's synchronous rescue can re-run it
+    # retries and the watchdog's synchronous rescue can re-run it.  It
+    # runs under the pool lock and may return the part of the copy that
+    # reads no pool (a swap-out's transfer and host store), run after
     copy_fn: Optional[object] = None
     retries: int = 0                  # failed attempts absorbed by retry
     failed: Optional[str] = None      # terminal copy error (retries spent)
@@ -262,10 +264,6 @@ class MultithreadingSwapManager:
                 self.ongoing_swap_out.append(task)
         return task
 
-    def _locked(self, fn):
-        with self._pool_lock:
-            return fn()
-
     def _run_copy_guarded(self, task: SwapTask,
                           deps: Sequence[Future]) -> None:
         """Run one task's data-plane copy with bounded retry.  NEVER
@@ -288,7 +286,9 @@ class MultithreadingSwapManager:
             while True:
                 try:
                     with self._pool_lock:
-                        task.copy_fn()
+                        rest = task.copy_fn()
+                    if rest is not None:
+                        rest()      # a swap-out's transfer and host store
                     return
                 except Exception as e:
                     attempt += 1
@@ -451,9 +451,12 @@ class MultithreadingSwapManager:
                 # terminal copy failure surfaced while we waited: one
                 # synchronous retried copy on the engine thread
                 try:
+                    rest = None
                     with self._pool_lock:
                         if t.copy_fn is not None:
-                            t.copy_fn()
+                            rest = t.copy_fn()
+                    if rest is not None:
+                        rest()
                     t.failed = None
                     with self._fail_lock:
                         if t in self.failed_tasks:

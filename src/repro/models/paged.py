@@ -102,6 +102,11 @@ def _decode_hidden(params, pool, block_tables, context_lens, tokens,
     return x[:, 0], new_pool
 
 
+def _head(params, cfg: ModelConfig):
+    """The LM head's parameters: the embedding table where tied."""
+    return params["embed"] if cfg.tie_embeddings else params["lm_head"]
+
+
 def _decode_core(params, pool, block_tables, context_lens, tokens,
                  cfg: ModelConfig, axis_name=None):
     """Legacy full-logits decode body (hidden body + replicated unembed).
@@ -109,8 +114,8 @@ def _decode_core(params, pool, block_tables, context_lens, tokens,
     x_last, new_pool = _decode_hidden(params, pool, block_tables,
                                       context_lens, tokens, cfg,
                                       axis_name=axis_name)
-    head = params["embed"] if cfg.tie_embeddings else params["lm_head"]
-    logits = L.unembed(head, x_last)
+    with jax.named_scope("unembed"):
+        logits = L.unembed(_head(params, cfg), x_last)
     next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     return next_tokens, logits, new_pool
 
@@ -209,18 +214,20 @@ def _device_step_core(params, pool, block_tables, context_lens, tokens,
     x_last, new_pool = _decode_hidden(params, pool, block_tables,
                                       context_lens, tokens, cfg,
                                       axis_name=axis_name)
-    head = params["embed"] if cfg.tie_embeddings else params["lm_head"]
+    head = _head(params, cfg)
     if axis_name is None or n_shards <= 1 \
             or cfg.vocab_size % n_shards != 0:
-        logits = L.unembed(head, x_last)
+        with jax.named_scope("unembed"):
+            logits = L.unembed(head, x_last)
         nxt = sample_tokens(logits, keys, context_lens, sampling)
     else:
         B = tokens.shape[0]
         Vs = cfg.vocab_size // n_shards
         shard = jax.lax.axis_index(axis_name)
-        w_local = jax.lax.dynamic_slice_in_dim(
-            head["table"], shard * Vs, Vs, axis=0)
-        local = L.unembed(head, x_last, table=w_local)     # (B, V/n)
+        with jax.named_scope("unembed"):
+            w_local = jax.lax.dynamic_slice_in_dim(
+                head["table"], shard * Vs, Vs, axis=0)
+            local = L.unembed(head, x_last, table=w_local)  # (B, V/n)
         vals = jnp.max(local, axis=-1)
         idxs = (jnp.argmax(local, axis=-1)
                 + shard * Vs).astype(jnp.int32)
@@ -378,8 +385,9 @@ def _prefill_chunk_core(params, tokens, k_carry, v_carry, prefix_len,
     # (C_pad, V) logits at (C_pad - 1)x the flops)
     x_last = jax.lax.dynamic_index_in_dim(x[0], chunk_len - 1, axis=0,
                                           keepdims=True)  # (1, d)
-    head = params["embed"] if cfg.tie_embeddings else params["lm_head"]
-    return L.unembed(head, x_last)[0], k_carry, v_carry
+    with jax.named_scope("unembed"):
+        logits = L.unembed(_head(params, cfg), x_last)[0]
+    return logits, k_carry, v_carry
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",),

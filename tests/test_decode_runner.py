@@ -421,3 +421,45 @@ def test_flush_is_idempotent_and_deferred(model):
     runner.flush()
     assert len(hist) == 2
     assert runner.stats.host_syncs == 1
+
+
+def test_row_writes_build_nothing_after_the_tables_and_land_exactly(model):
+    """Every count of changed rows is written by a program built with the
+    tables (one per pow2 count), so row writes after ``_rebuild`` trace,
+    lower and build nothing; each write lands on its rows alone, a
+    block-table-only row keeping its counters."""
+    from repro.core import tracing
+    cfg, params = model
+    runner = DecodeRunner({"cfg": cfg, "params": params},
+                          block_size=BS, trash_block=15)
+    runner._rebuild([], 4, 8)
+    runner._row_key(0)                  # the key fold's program
+    tr = tracing.tracer()
+    seen = {s.id for s in tr.spans()}
+    want_bt = np.full((8, 4), 15, np.int32)
+    want_ctx = np.zeros((8,), np.int32)
+    want_tok = np.zeros((8,), np.int32)
+    want_keys = np.zeros((8, 2), np.uint32)
+    for n in range(1, 9):
+        rows = list(range(8 - n, 8))
+        pending = {}
+        for j, r in enumerate(rows):
+            ids = tuple(range(j % 4 + 1))
+            want_bt[r] = 15
+            want_bt[r, :len(ids)] = ids
+            if j % 2:                   # block table only
+                pending[r] = (ids, None, None, None, None)
+            else:
+                pending[r] = (ids, 10 * n + j, 100 + r,
+                              np.asarray([n, r], np.uint32),
+                              np.asarray([0.0, 0.0, 1.0], np.float32))
+                want_ctx[r], want_tok[r] = 10 * n + j, 100 + r
+                want_keys[r] = n, r
+        runner._scatter_rows(pending)
+        np.testing.assert_array_equal(np.asarray(runner._bt), want_bt)
+        np.testing.assert_array_equal(np.asarray(runner._ctx), want_ctx)
+        np.testing.assert_array_equal(np.asarray(runner._tok), want_tok)
+        np.testing.assert_array_equal(np.asarray(runner._keys), want_keys)
+    built = [s.name for s in tr.spans() if s.id not in seen
+             and s.name.split(":")[0] in ("trace", "lower", "build")]
+    assert built == []

@@ -127,6 +127,45 @@ def test_gather_scatter_bucketing_bounds_jit_cache():
     assert ops.swap_scatter_cache_size() - s0 == grown_s
 
 
+def test_copy_out_builds_no_program_for_a_new_block_count():
+    """Once a slab bucket has run, a swap-out of a block count new to the
+    process traces, lowers and builds nothing: the whole pow2 slab
+    crosses to the host and is trimmed there (a slice on the device
+    built one program per count, inside serving steps)."""
+    from repro.core import tracing
+    pools = _pools(nb=40, ncpu=64)
+    snap = np.asarray(pools.gpu).view(np.uint16)
+    for n in (1, 2, 3, 5, 9):                 # one count of each bucket
+        pools.copy_out_staged([(0, n)], list(range(n)))
+    tr = tracing.tracer()
+    seen = {s.id for s in tr.spans()}
+    for n in (4, 6, 7, 8, 10, 11, 12, 13, 14, 15, 16):
+        pools.copy_out_staged([(1, n)], list(range(20, 20 + n)))
+        np.testing.assert_array_equal(pools.cpu[:, :, 20:20 + n],
+                                      snap[:, :, 1:1 + n])
+    built = [s.name for s in tr.spans() if s.id not in seen
+             and s.name.split(":")[0] in ("trace", "lower", "build")]
+    assert built == []
+
+
+def test_deferred_copy_out_has_read_the_pool_when_it_returns():
+    """``deferred=True`` returns the transfer and the host store, to run
+    without the pool lock: the pool may be overwritten (donated) before
+    they run, and the host still receives the blocks as they were."""
+    pools = _pools()
+    snap = np.asarray(pools.gpu).view(np.uint16)
+    runs = [(1, 3), (9, 2)]
+    cpu_ids = [4, 0, 7, 12, 3]
+    store = pools.copy_out_staged(runs, cpu_ids, deferred=True)
+    assert not pools.cpu.any()          # nothing stored yet
+    pools.copy_in_staged(cpu_ids, runs)  # zero blocks, pool donated
+    assert not np.asarray(pools.gpu).view(np.uint16)[:, :, 1:4].any()
+    store()
+    np.testing.assert_array_equal(pools.cpu[:, :, cpu_ids],
+                                  snap[:, :, [1, 2, 3, 9, 10]])
+    assert pools.copy_out_staged(runs, cpu_ids) is None
+
+
 def test_copy_in_double_buffered_bit_exact_multi_stage():
     """Double-buffered swap-in (bounded sub-slabs): splitting a staged
     copy-in mid-run must land every block bit-exactly, leak into no

@@ -3,9 +3,11 @@
 Interpret mode (what every other kernel test runs on the CPU) never
 checks Mosaic's tiling rules or VMEM limits; the TPU compiler installed
 with JAX does, for a chip that is described and not attached.  Shapes
-are Qwen2-1.5B's serving widths: 28 layers, 2 KV heads, head_dim 128,
-12 query heads, block size 16, a bf16 pool and bf16 queries (the dtype
-the decode step passes).
+are the benchmarked configurations' serving widths, block size 16, a
+bf16 pool and bf16 queries (the dtype the decode step passes):
+Qwen2-1.5B (28 layers, 12/2 heads x 128, a 1,024-block pool) and
+Mistral-Nemo-12B at 4 of its 40 layers (32/8 heads x 128, a 4,608-block
+pool).
 
 The topology is described inside a module-scoped fixture, never while
 a module is imported: only one process at a time may load the TPU
@@ -23,8 +25,10 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels import ops
 from repro.kernels.paged_attention import paged_attention
 
-L, HQ, HKV, D, BS = 28, 12, 2, 128, 16
-NB = 1024                       # pool blocks (16k tokens)
+D, BS = 128, 16
+# (layers, q heads, KV heads, pool blocks)
+QWEN2 = (28, 12, 2, 1024)
+NEMO = (4, 32, 8, 4608)
 
 
 @pytest.fixture(scope="module")
@@ -50,12 +54,16 @@ def _spec(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-@pytest.mark.parametrize("B,n_pages", [(32, 256), (3, 5)],
-                         ids=["chat-bucket", "ragged"])
-def test_paged_attention_compiles_for_v5e(one_chip, B, n_pages):
-    """The chat cell's bucket (32 rows x 256 pages), and a small bucket
-    whose page count no power of two divides; the tile comes from the
-    shapes."""
+@pytest.mark.parametrize("model,B,n_pages", [
+    pytest.param(QWEN2, 32, 256, id="chat-bucket"),
+    pytest.param(QWEN2, 3, 5, id="ragged"),
+    pytest.param(NEMO, 32, 128, id="nemo-bulk-bucket"),
+    pytest.param(NEMO, 3, 5, id="nemo-ragged")])
+def test_paged_attention_compiles_for_v5e(one_chip, model, B, n_pages):
+    """Each cell's bucket (the chat cell's 32 rows x 256 pages, the bulk
+    cell's 32 x 128), and a small bucket whose page count no power of
+    two divides; the tile comes from the shapes."""
+    L, HQ, HKV, NB = model
     fn = functools.partial(paged_attention, scale=D ** -0.5,
                            interpret=False)
     compiled = jax.jit(fn).lower(
@@ -67,8 +75,12 @@ def test_paged_attention_compiles_for_v5e(one_chip, B, n_pages):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("n_slab,run_blocks", [(64, 8), (256, 256)])
-def test_staged_gather_compiles_for_v5e(one_chip, n_slab, run_blocks):
+@pytest.mark.parametrize("model,n_slab,run_blocks", [
+    pytest.param(QWEN2, 64, 8, id="64-8"),
+    pytest.param(QWEN2, 256, 256, id="256-256"),
+    pytest.param(NEMO, 64, 64, id="nemo-64-64")])
+def test_staged_gather_compiles_for_v5e(one_chip, model, n_slab, run_blocks):
+    L, _, HKV, NB = model
     n_runs = 8
     compiled = ops._gather_swap.lower(
         _spec((L, 2, NB, BS, HKV, D), jnp.bfloat16, one_chip),
@@ -80,6 +92,7 @@ def test_staged_gather_compiles_for_v5e(one_chip, n_slab, run_blocks):
 
 
 def test_donated_staged_scatter_compiles_for_v5e(one_chip):
+    L, _, HKV, NB = QWEN2
     n_runs, n_slab, run_blocks = 8, 64, 8
     compiled = ops._scatter_swap.lower(
         _spec((L, 2, NB, BS, HKV, D), jnp.bfloat16, one_chip),

@@ -401,7 +401,8 @@ def test_device_programs_carry_the_scope_names(tiny_model):
             runs, runs, run_blocks=1, interpret=True)),
     }
     for prog, scope in [("decode", "decode_layers"), ("decode", "paged_attn"),
-                        ("decode", "sampling"), ("prefill", "prefill_forward"),
+                        ("decode", "sampling"), ("decode", "unembed"),
+                        ("prefill", "prefill_forward"), ("prefill", "unembed"),
                         ("insert", "prefill_insert"), ("seed", "carry_seed"),
                         ("grow", "carry_grow"), ("gather", "swap_gather"),
                         ("scatter", "swap_scatter")]:
