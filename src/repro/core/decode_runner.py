@@ -24,6 +24,10 @@ fixed-shape (DESIGN.md §3):
     uploads nothing at all.
   * **Pool donation** — ``paged_decode_step_device`` donates pool,
     context and token arrays; the per-layer KV write is in-place.
+  * **Compute-dtype weights** — the step reads a view of the params
+    with the layer matrices converted to the compute dtype once, at the
+    runner's build (``models.paged.decode_weights``), so no step re-casts
+    a weight table; prefill reads the params as given.
   * **Deferred host sync** — the next-token array is NOT pulled to the
     host at dispatch.  It is materialized lazily (``flush``) at the
     start of the NEXT decode — after the engine's control plane for that
@@ -79,7 +83,7 @@ import numpy as np
 
 from repro.core import tracing
 from repro.kernels import ops
-from repro.models.paged import (paged_decode_step_device,
+from repro.models.paged import (decode_weights, paged_decode_step_device,
                                 paged_decode_step_device_sharded,
                                 sample_tokens)
 
@@ -198,6 +202,13 @@ class DecodeRunner:
                 lambda x, s: jax.device_put(
                     x, jax.sharding.NamedSharding(mesh, s)),
                 self._params, serving_param_pspecs(self._params))
+        # the decode step reads its weights in the dtype it computes in,
+        # converted once here (``decode_weights``); prefill keeps
+        # ``self._params``, the tree its callers compile prefill with
+        self._decode_params, weights = decode_weights(
+            self._params, model_bundle["cfg"])
+        self._trace = tracing.tracer()
+        self._trace.mark("decode.weights", **weights)
         # sampling config: the runner-wide default row of the per-row
         # (B, 3) [temperature, top_k, top_p] array the fused sampler
         # traces (values never force a compile — the shape follows the
@@ -227,7 +238,6 @@ class DecodeRunner:
         # in-flight chunked prefills, keyed by rid (DESIGN.md §5)
         self._prefills: Dict[int, _PrefillState] = {}
         self.stats = RunnerStats()
-        self._trace = tracing.tracer()
 
     @property
     def batch_bucket(self) -> int:
@@ -444,14 +454,14 @@ class DecodeRunner:
             if self._mesh is None:
                 nxt, pool, self._ctx, self._tok = \
                     paged_decode_step_device(
-                        self._params, pool, self._bt, self._ctx, self._tok,
-                        self._active, self._keys, self._sampling,
+                        self._decode_params, pool, self._bt, self._ctx,
+                        self._tok, self._active, self._keys, self._sampling,
                         cfg=self.mb["cfg"])
             else:
                 nxt, pool, self._ctx, self._tok = \
                     paged_decode_step_device_sharded(
-                        self._params, pool, self._bt, self._ctx, self._tok,
-                        self._active, self._keys, self._sampling,
+                        self._decode_params, pool, self._bt, self._ctx,
+                        self._tok, self._active, self._keys, self._sampling,
                         cfg=self.mb["cfg"], mesh=self._mesh)
         self._pending = ([(self._rows[v.rid], v.token_history)
                           for v in views], nxt)

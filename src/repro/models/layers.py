@@ -112,7 +112,10 @@ def init_embedding(key, vocab: int, d_model: int):
 
 
 def embed(params, tokens, dtype=COMPUTE_DTYPE):
-    return params["table"].astype(dtype)[tokens]
+    """The rows of ``tokens``, gathered and then cast: the same values as
+    casting the whole table first, without moving all of it."""
+    with jax.named_scope("embed"):
+        return params["table"][tokens].astype(dtype)
 
 
 def unembed(params, x, table=None):

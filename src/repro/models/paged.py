@@ -21,6 +21,9 @@ Hot-path contract (see DESIGN.md §3):
     it per step); the step folds each row's position in on device.
   * Shapes (batch, n_pages) must be bucketed by the caller — every unique
     shape is one XLA compilation.
+  * The DecodeRunner hands the device steps ``decode_weights(params)``:
+    the layer matrices already in the compute dtype, so the step casts
+    no weight table (the same values it would cast itself).
 """
 from __future__ import annotations
 
@@ -105,6 +108,47 @@ def _decode_hidden(params, pool, block_tables, context_lens, tokens,
 def _head(params, cfg: ModelConfig):
     """The LM head's parameters: the embedding table where tied."""
     return params["embed"] if cfg.tie_embeddings else params["lm_head"]
+
+
+# the leaves the dense decode body reads only through
+# ``.astype(COMPUTE_DTYPE)``, by their path in the uniform layout
+_DECODE_CAST_ATTN = {("layers", "attn", n)
+                     for n in ("wq", "wk", "wv", "wo", "bq", "bk", "bv")}
+_DECODE_CAST_FFN = {("layers", "ffn", n)
+                    for n in ("w_gate", "w_up", "w_down")}
+
+
+def decode_weights(params, cfg: ModelConfig):
+    """The tree the decode step reads: ``params`` with each leaf the
+    dense body only ever casts to ``COMPUTE_DTYPE`` (the layers' q/k/v/o
+    projections and biases, and the SwiGLU matrices) held in that dtype,
+    converted once here instead of on every step.  The step computes
+    with exactly the values it cast itself before, so its tokens are
+    bit-identical.  Every other leaf is the same array object: the norms
+    and the head are read in float32, the embedding is gathered before
+    it is cast, and MoE experts are left as they are.  A converted leaf
+    keeps its sharding; one already in ``COMPUTE_DTYPE`` is the same
+    array.
+
+    Returns (view, stats): ``cast_bytes``, the bytes converted;
+    ``leaves_cast``; ``f32_bytes``, the float32 bytes the step still
+    reads whole (the head, the norms, any expert weights)."""
+    cast = _DECODE_CAST_ATTN | (_DECODE_CAST_FFN if cfg.moe is None
+                                else set())
+    stats = {"cast_bytes": 0, "leaves_cast": 0, "f32_bytes": 0}
+
+    def leaf(path, x):
+        names = tuple(getattr(k, "key", str(k)) for k in path)
+        if names in cast and x.dtype != L.COMPUTE_DTYPE:
+            stats["cast_bytes"] += x.nbytes
+            stats["leaves_cast"] += 1
+            return x.astype(L.COMPUTE_DTYPE)      # keeps x's sharding
+        gathered = names == ("embed", "table") and not cfg.tie_embeddings
+        if x.dtype == jnp.float32 and not gathered:
+            stats["f32_bytes"] += x.nbytes
+        return x
+
+    return jax.tree_util.tree_map_with_path(leaf, params), stats
 
 
 def _decode_core(params, pool, block_tables, context_lens, tokens,
